@@ -166,6 +166,7 @@ def dap_block_overlap_paths():
     band for the max-composed cost model.  ``bytes`` is the per-device
     per-block collective payload (dap_comm_bytes, fp32)."""
     import json
+    import os
     import subprocess
     import sys
 
@@ -183,9 +184,9 @@ from jax.sharding import PartitionSpec as P
 from repro.core.config import af2_tiny
 from repro.core import model as af2
 from repro.parallel import dap as dap_lib
-from repro.parallel.mesh_utils import smap
+from repro.parallel.mesh_utils import make_mesh, smap
 
-mesh = jax.make_mesh(({dap},), ("dap",))
+mesh = make_mesh(({dap},), ("dap",))
 out = {{}}
 for (s, r) in {shapes!r}:
     cfg = af2_tiny(variant="parallel", n_seq=s, n_res=r)
@@ -215,8 +216,11 @@ for (s, r) in {shapes!r}:
                           for k, ts in times.items()}}
 print("RESULT " + json.dumps(out))
 """
+    # the child is pinned to the CPU: this parent may hold the chip, and
+    # the rows measure the fake-device CPU schedule by design
     proc = subprocess.run([sys.executable, "-c", code], capture_output=True,
-                          text=True, timeout=900)
+                          text=True, timeout=900,
+                          env=dict(os.environ, JAX_PLATFORMS="cpu"))
     if proc.returncode != 0:
         raise RuntimeError(f"dap_block subprocess failed:\n"
                            f"{proc.stderr[-2000:]}")

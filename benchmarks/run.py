@@ -74,6 +74,8 @@ def main() -> None:
                          "previously-committed row")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     print("name,us_per_call,derived")
     from benchmarks import paper_tables, kernel_bench, fold_bench, train_bench
     from benchmarks import data_bench

@@ -15,6 +15,7 @@ from repro.data.loader import ShardedLoader
 from repro.data.tokens import token_batch
 from repro.models import dense
 from repro.models.lmconfig import LMConfig
+from repro.parallel.mesh_utils import make_mesh
 from repro.train.checkpoint import CheckpointManager, StepWatchdog
 from repro.train.optim import adamw, warmup_cosine
 from repro.train.trainstep import make_lm_train_step
@@ -35,7 +36,7 @@ cfg = LMConfig(arch_id="lm100m", family="dense", n_layer=args.layers,
                vocab=args.vocab, scan_layers=True, remat="none",
                attention_chunk=128)
 model = dense
-mesh = jax.make_mesh((len(jax.devices()), 1), ("data", "model"))
+mesh = make_mesh((len(jax.devices()), 1), ("data", "model"))
 opt = adamw(warmup_cosine(3e-4, 20, args.steps), clip_norm=1.0)
 step_fn, _, _ = make_lm_train_step(model, cfg, opt, mesh)
 

@@ -1,13 +1,26 @@
-"""Roofline terms from dry-run artifacts (TPU v5e constants per spec)."""
+"""Roofline terms from dry-run artifacts, priced with published chip peaks."""
 from __future__ import annotations
 
 import dataclasses
+from typing import Optional
+
+
+# Published peaks of one chip, keyed by ``jax.Device.device_kind``: bf16
+# FLOP/s and HBM B/s.  A measured time is divided only by the peaks of the
+# kind it was measured on; a device kind that is not here is an error,
+# never a default.
+DEVICE_PEAKS = {
+    # Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 819 GB/s HBM
+    "TPU v5 lite": {"peak_flops": 197e12, "hbm_bw": 819e9},
+}
+# the chip the roofline *model* (predictions, paper tables) is priced for
+MODEL_KIND = "TPU v5 lite"
 
 
 @dataclasses.dataclass(frozen=True)
 class HW:
-    peak_flops: float = 197e12      # bf16 FLOP/s per chip
-    hbm_bw: float = 819e9           # B/s per chip
+    peak_flops: float = DEVICE_PEAKS[MODEL_KIND]["peak_flops"]
+    hbm_bw: float = DEVICE_PEAKS[MODEL_KIND]["hbm_bw"]
     ici_bw: float = 50e9            # B/s per link
     # per-collective dispatch/sync overhead (DAP issues ~13 collectives per
     # Evoformer block vs BP's single fused psum — at initial-training shapes
@@ -25,6 +38,20 @@ class HW:
     # intra-block transposes/gathers rely on the async-collective scheduler
     # finding shorter-range slack (the --print-tpu-env preset)
     overlap_eff: float = 0.5
+
+
+def device_peaks(device_kind: str) -> Optional[HW]:
+    """Peaks of one ``device_kind`` chip.  ``None`` for the host CPU, which
+    has no peak a utilization could be taken against; an accelerator kind
+    missing from ``DEVICE_PEAKS`` raises."""
+    if device_kind == "cpu":
+        return None
+    if device_kind not in DEVICE_PEAKS:
+        raise KeyError(
+            f"no published peaks for device kind {device_kind!r}; add them "
+            f"with their source to DEVICE_PEAKS (known: "
+            f"{sorted(DEVICE_PEAKS)})")
+    return HW(**DEVICE_PEAKS[device_kind])
 
 
 def roofline_terms(*, total_flops: float, total_bytes: float,

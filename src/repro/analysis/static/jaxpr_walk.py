@@ -15,7 +15,7 @@ import numpy as np
 
 def _subjaxpr_items(eqn):
     """(kind_name, core.Jaxpr) pairs hiding inside an eqn's params."""
-    from jax import core
+    from jax.extend import core
     for key, val in eqn.params.items():
         items = val if isinstance(val, (tuple, list)) else (val,)
         for it in items:

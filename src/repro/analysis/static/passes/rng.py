@@ -79,13 +79,13 @@ class RngTracer:
         return f"{tag}#{next(self._fresh)}"
 
     def _read(self, env, atom):
-        from jax import core
+        from jax.extend import core
         if isinstance(atom, core.Literal):
             return ("lit", atom.val)
         return env.get(atom)
 
     def _varying(self, varying, atom):
-        from jax import core
+        from jax.extend import core
         return (not isinstance(atom, core.Literal)) and atom in varying
 
     # -- the walk ---------------------------------------------------------
@@ -106,7 +106,7 @@ class RngTracer:
         return self
 
     def _walk(self, jaxpr, env, varying, loop_depth, path):
-        from jax import core
+        from jax.extend import core
         for eqn in jaxpr.eqns:
             prim = eqn.primitive.name
             sub_path = f"{path}/{prim}" if path else prim
@@ -114,7 +114,7 @@ class RngTracer:
             if handler is not None:
                 handler(eqn, env, varying, loop_depth, sub_path)
                 continue
-            if prim in ("pjit", "closed_call", "core_call", "remat",
+            if prim in ("jit", "closed_call", "core_call", "remat",
                         "checkpoint", "remat2", "custom_jvp_call",
                         "custom_vjp_call", "custom_vjp_call_jaxpr",
                         "custom_jvp_call_jaxpr", "shard_map"):
@@ -243,7 +243,7 @@ class RngTracer:
                 sub_env[cv] = _Key(self.fresh("const"))
         self._walk(sub, sub_env, sub_varying, depth, path)
         for outer, inner in zip(eqn.outvars, sub.outvars):
-            from jax import core
+            from jax.extend import core
             if isinstance(inner, core.Var):
                 val = sub_env.get(inner)
                 if isinstance(val, (_Key, _KeyArr, _Raw)):

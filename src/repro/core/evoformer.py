@@ -279,7 +279,10 @@ def opm_contract(a: jnp.ndarray, b: jnp.ndarray, w: jnp.ndarray,
     # this impl exists to avoid, just split across the ys of the scan
     out = jax.lax.map(jax.checkpoint(one_chunk), chunks)      # (n, rc, r_j, z)
     out = out.reshape(-1, b.shape[1], wr.shape[-1])[:r_i]
-    return out + b_out
+    # bias added in f32, as in nn.dense: its gradient sums all r_i x r_j
+    # positions, and DAP splits the r_i rows across devices
+    return (out.astype(jnp.float32) + b_out.astype(jnp.float32)).astype(
+        jnp.result_type(out.dtype, b_out.dtype))
 
 
 def outer_product_mean_fused(p: Params, msa: jnp.ndarray, *,

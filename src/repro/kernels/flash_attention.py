@@ -30,8 +30,26 @@ from jax.experimental import pallas as pl
 NEG_INF = -1e30
 
 
+def contract_precision(dtype) -> Optional[jax.lax.Precision]:
+    """Precision of the in-kernel contractions for inputs of ``dtype``.
+
+    Mosaic, like XLA on a TPU, rounds f32 operands to a single bf16 MXU
+    pass unless told otherwise; the interpreter does not.  Kernels whose
+    inputs are f32 therefore ask for f32 contractions, and bf16 inputs keep
+    the single pass.  Accumulation is f32 either way."""
+    return jax.lax.Precision.HIGHEST if dtype == jnp.float32 else None
+
+
+def mxu_dot(precision):
+    """``dot_general`` accumulating in f32 at ``precision``."""
+    return functools.partial(jax.lax.dot_general,
+                             preferred_element_type=jnp.float32,
+                             precision=precision)
+
+
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, scale: float, block_k: int,
-                  causal: bool, q_block: int, seq_k: int):
+                  causal: bool, q_block: int, seq_k: int, precision):
+    dot = mxu_dot(precision)
     qi = pl.program_id(1)
     q = q_ref[...]                                  # (block_q, D)
     m = jnp.full((q.shape[0],), NEG_INF, jnp.float32)
@@ -48,11 +66,10 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, scale: float, block_k: int,
 
     def body(kb, carry):
         m, l, acc = carry
-        k = pl.load(k_ref, (pl.dslice(kb * block_k, block_k), slice(None)))
-        v = pl.load(v_ref, (pl.dslice(kb * block_k, block_k), slice(None)))
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale   # (bq, bk)
+        k = k_ref[pl.ds(kb * block_k, block_k), :]
+        v = v_ref[pl.ds(kb * block_k, block_k), :]
+        s = dot(
+            q, k, (((1,), (1,)), ((), ()))) * scale   # (bq, bk)
         if causal:
             qpos = qi * q_block + jax.lax.broadcasted_iota(
                 jnp.int32, s.shape, 0)
@@ -65,9 +82,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, scale: float, block_k: int,
             p = jnp.where(qpos >= kpos, p, 0.0)
         corr = jnp.exp(m - m_new)
         l_new = l * corr + jnp.sum(p, axis=1)
-        acc_new = acc * corr[:, None] + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        acc_new = acc * corr[:, None] + dot(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())))
         return m_new, l_new, acc_new
 
     m, l, acc = jax.lax.fori_loop(0, n_needed, body, (m, l, acc))
@@ -77,7 +93,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, scale: float, block_k: int,
 def flash_attention_fwd(q, k, v, *, causal: bool = True,
                         scale: Optional[float] = None,
                         block_q: int = 128, block_k: int = 128,
-                        interpret: bool = True) -> jnp.ndarray:
+                        interpret: bool) -> jnp.ndarray:
     """q (B,S,H,D); k/v (B,T,KV,D) with H = KV*G. Forward only."""
     b, s, h, d = q.shape
     t, kv = k.shape[1], k.shape[2]
@@ -96,7 +112,8 @@ def flash_attention_fwd(q, k, v, *, causal: bool = True,
     grid = (b * kv * g, s // block_q)
     out = pl.pallas_call(
         functools.partial(_flash_kernel, scale=scale, block_k=block_k,
-                          causal=causal, q_block=block_q, seq_k=t),
+                          causal=causal, q_block=block_q, seq_k=t,
+                          precision=contract_precision(q.dtype)),
         out_shape=jax.ShapeDtypeStruct((b * kv * g, s, d), q.dtype),
         grid=grid,
         in_specs=[
@@ -134,7 +151,8 @@ def evo_supported(s: int, min_block: int = 8) -> bool:
 
 def _evo_kernel(q_ref, k_ref, v_ref, bias_ref, gate_ref, o_ref, *rest,
                 scale: float, block_k: int, seq_k: int, biased: bool,
-                gated: bool):
+                gated: bool, precision):
+    dot = mxu_dot(precision)
     q = q_ref[...]                                   # (block_q, C)
     m = jnp.full((q.shape[0],), NEG_INF, jnp.float32)
     l = jnp.zeros((q.shape[0],), jnp.float32)
@@ -142,22 +160,19 @@ def _evo_kernel(q_ref, k_ref, v_ref, bias_ref, gate_ref, o_ref, *rest,
 
     def body(kb, carry):
         m, l, acc = carry
-        ks = pl.load(k_ref, (pl.dslice(kb * block_k, block_k), slice(None)))
-        vs = pl.load(v_ref, (pl.dslice(kb * block_k, block_k), slice(None)))
-        s = jax.lax.dot_general(
-            q, ks, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
+        ks = k_ref[pl.ds(kb * block_k, block_k), :]
+        vs = v_ref[pl.ds(kb * block_k, block_k), :]
+        s = dot(
+            q, ks, (((1,), (1,)), ((), ()))) * scale
         if biased:
-            bs = pl.load(bias_ref,
-                         (slice(None), pl.dslice(kb * block_k, block_k)))
+            bs = bias_ref[:, pl.ds(kb * block_k, block_k)]
             s = s + bs.astype(jnp.float32)
         m_new = jnp.maximum(m, jnp.max(s, axis=1))
         p = jnp.exp(s - m_new[:, None])
         corr = jnp.exp(m - m_new)
         l_new = l * corr + jnp.sum(p, axis=1)
-        acc_new = acc * corr[:, None] + jax.lax.dot_general(
-            p.astype(vs.dtype), vs, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        acc_new = acc * corr[:, None] + dot(
+            p.astype(vs.dtype), vs, (((1,), (0,)), ((), ())))
         return m_new, l_new, acc_new
 
     m, l, acc = jax.lax.fori_loop(0, seq_k // block_k, body, (m, l, acc))
@@ -166,8 +181,9 @@ def _evo_kernel(q_ref, k_ref, v_ref, bias_ref, gate_ref, o_ref, *rest,
     if gated:
         o = o * jax.nn.sigmoid(gate_ref[...].astype(jnp.float32))
     o_ref[...] = o.astype(o_ref.dtype)
-    if rest:  # residual mode: per-row log-sum-exp for the flash backward
-        rest[0][...] = m + jnp.log(l_safe)
+    if rest:  # residual mode: per-row log-sum-exp for the flash backward,
+        # stored lane-major as a (1, block_q) row of the (L*H, 1, S) array
+        rest[0][...] = (m + jnp.log(l_safe)).reshape(1, -1)
 
 
 def _dummy_operand(dtype):
@@ -180,8 +196,7 @@ def _dummy_operand(dtype):
 
 def evo_attention_fwd(q, k, v, bias, gate, *, scale: Optional[float] = None,
                       block_q: int = 128, block_k: int = 128,
-                      interpret: bool = True,
-                      return_residuals: bool = False):
+                      interpret: bool, return_residuals: bool = False):
     """AF2 fused gated bias attention (paper hot path — Evoformer row/triangle
     attention is 62-78%% of step time, Table 2).
 
@@ -190,8 +205,9 @@ def evo_attention_fwd(q, k, v, bias, gate, *, scale: Optional[float] = None,
     attention output).  ``gate`` holds pre-sigmoid logits; ``bias=None`` /
     ``gate=None`` compile the bias add / gate epilogue out of the kernel
     entirely (no dummy operand traffic).  With ``return_residuals=True`` also
-    returns the (L*H, S) fp32 log-sum-exp rows consumed by
-    :func:`evo_attention_bwd`.
+    returns the (L*H, 1, S) fp32 log-sum-exp rows consumed by
+    :func:`evo_attention_bwd`: the sequence rides the lane dim, so a
+    (1, block_q) block meets Mosaic's (8, 128) block-tiling rule.
     """
     lrows, s, h, c = q.shape
     biased, gated = bias is not None, gate is not None
@@ -218,13 +234,16 @@ def evo_attention_fwd(q, k, v, bias, gate, *, scale: Optional[float] = None,
     out_shape = [jax.ShapeDtypeStruct((lrows * h, s, c), q.dtype)]
     out_specs = [pl.BlockSpec((None, block_q, c), lambda i, j: (i, j, 0))]
     if return_residuals:
-        out_shape.append(jax.ShapeDtypeStruct((lrows * h, s), jnp.float32))
-        out_specs.append(pl.BlockSpec((None, block_q), lambda i, j: (i, j)))
+        out_shape.append(
+            jax.ShapeDtypeStruct((lrows * h, 1, s), jnp.float32))
+        out_specs.append(
+            pl.BlockSpec((None, 1, block_q), lambda i, j: (i, 0, j)))
 
     grid = (lrows * h, s // block_q)
     res = pl.pallas_call(
         functools.partial(_evo_kernel, scale=scale, block_k=block_k, seq_k=s,
-                          biased=biased, gated=gated),
+                          biased=biased, gated=gated,
+                          precision=contract_precision(q.dtype)),
         out_shape=out_shape,
         grid=grid,
         in_specs=[
@@ -246,15 +265,16 @@ def evo_attention_fwd(q, k, v, bias, gate, *, scale: Optional[float] = None,
 def _evo_bwd_dq_kernel(q_ref, k_ref, v_ref, bias_ref, gate_ref, out_ref,
                        do_ref, lse_ref, dq_ref, dgate_ref, dbias_ref, *,
                        scale: float, block_k: int, seq_k: int, biased: bool,
-                       gated: bool):
+                       gated: bool, precision):
     """dq + dgate for one (head, q-block, lead-row) program; dbias accumulates
     across the innermost lead-row grid axis (the head reduction over MSA
     rows), so the (H, S, S) bias gradient is built without recomputation."""
+    dot = mxu_dot(precision)
     li = pl.program_id(2)
     q = q_ref[...]                                       # (bq, C)
     do = do_ref[...].astype(jnp.float32)
     out = out_ref[...].astype(jnp.float32)
-    lse = lse_ref[...]                                   # (bq,)
+    lse = lse_ref[...].reshape(-1)                       # (bq,)
     if gated:
         sig = jax.nn.sigmoid(gate_ref[...].astype(jnp.float32))
         # out = sig * o_raw, so o_raw*sig == out: no division needed
@@ -270,25 +290,21 @@ def _evo_bwd_dq_kernel(q_ref, k_ref, v_ref, bias_ref, gate_ref, out_ref,
             dbias_ref[...] = jnp.zeros_like(dbias_ref)
 
     def body(kb, dq):
-        kslice = (pl.dslice(kb * block_k, block_k), slice(None))
-        ks = pl.load(k_ref, kslice)
-        vs = pl.load(v_ref, kslice)
-        s = jax.lax.dot_general(
-            q, ks, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        bsl = (slice(None), pl.dslice(kb * block_k, block_k))
+        ksl = pl.ds(kb * block_k, block_k)
+        ks = k_ref[ksl, :]
+        vs = v_ref[ksl, :]
+        s = dot(
+            q, ks, (((1,), (1,)), ((), ()))) * scale
         if biased:
-            s = s + pl.load(bias_ref, bsl).astype(jnp.float32)
+            s = s + bias_ref[:, ksl].astype(jnp.float32)
         p = jnp.exp(s - lse[:, None])                    # (bq, bk)
-        dp = jax.lax.dot_general(
-            do_raw.astype(vs.dtype), vs, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        dp = dot(
+            do_raw.astype(vs.dtype), vs, (((1,), (1,)), ((), ())))
         ds = p * (dp - delta[:, None])                   # (bq, bk) fp32
         if biased:
-            pl.store(dbias_ref, bsl, pl.load(dbias_ref, bsl) + ds)
-        return dq + jax.lax.dot_general(
-            ds.astype(ks.dtype), ks, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
+            dbias_ref[:, ksl] = dbias_ref[:, ksl] + ds
+        return dq + dot(
+            ds.astype(ks.dtype), ks, (((1,), (0,)), ((), ()))) * scale
 
     dq = jnp.zeros((q.shape[0], q.shape[1]), jnp.float32)
     dq = jax.lax.fori_loop(0, seq_k // block_k, body, dq)
@@ -298,43 +314,37 @@ def _evo_bwd_dq_kernel(q_ref, k_ref, v_ref, bias_ref, gate_ref, out_ref,
 def _evo_bwd_dkv_kernel(q_ref, k_ref, v_ref, bias_ref, gate_ref, out_ref,
                         do_ref, lse_ref, dk_ref, dv_ref, *,
                         scale: float, block_q: int, seq_q: int, biased: bool,
-                        gated: bool):
+                        gated: bool, precision):
     """dk + dv for one (lead-row*head, k-block) program, streaming q-blocks."""
+    dot = mxu_dot(precision)
     k = k_ref[...]                                       # (bk, C)
     v = v_ref[...]
 
     def body(jq, carry):
         dk, dv = carry
-        qslice = (pl.dslice(jq * block_q, block_q), slice(None))
-        q = pl.load(q_ref, qslice)
-        do = pl.load(do_ref, qslice).astype(jnp.float32)
-        out = pl.load(out_ref, qslice).astype(jnp.float32)
-        lse = pl.load(lse_ref, (pl.dslice(jq * block_q, block_q),))
+        qsl = pl.ds(jq * block_q, block_q)
+        q = q_ref[qsl, :]
+        do = do_ref[qsl, :].astype(jnp.float32)
+        out = out_ref[qsl, :].astype(jnp.float32)
+        lse = lse_ref[:, qsl].reshape(-1)                # (bq,)
         if gated:
-            sig = jax.nn.sigmoid(
-                pl.load(gate_ref, qslice).astype(jnp.float32))
+            sig = jax.nn.sigmoid(gate_ref[qsl, :].astype(jnp.float32))
             do_raw = do * sig
         else:
             do_raw = do
         delta = jnp.sum(do * out, axis=1)                # (bq,)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
+        s = dot(
+            q, k, (((1,), (1,)), ((), ()))) * scale
         if biased:
-            bs = pl.load(bias_ref,
-                         (pl.dslice(jq * block_q, block_q), slice(None)))
-            s = s + bs.astype(jnp.float32)
+            s = s + bias_ref[qsl, :].astype(jnp.float32)
         p = jnp.exp(s - lse[:, None])                    # (bq, bk)
-        dv = dv + jax.lax.dot_general(
-            p.astype(do_raw.dtype), do_raw, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(
-            do_raw.astype(v.dtype), v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        dv = dv + dot(
+            p.astype(do_raw.dtype), do_raw, (((0,), (0,)), ((), ())))
+        dp = dot(
+            do_raw.astype(v.dtype), v, (((1,), (1,)), ((), ())))
         ds = p * (dp - delta[:, None])
-        dk = dk + jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
+        dk = dk + dot(
+            ds.astype(q.dtype), q, (((0,), (0,)), ((), ()))) * scale
         return dk, dv
 
     dk0 = jnp.zeros((k.shape[0], k.shape[1]), jnp.float32)
@@ -347,10 +357,10 @@ def _evo_bwd_dkv_kernel(q_ref, k_ref, v_ref, bias_ref, gate_ref, out_ref,
 def evo_attention_bwd(q, k, v, bias, gate, out, lse, do, *,
                       scale: Optional[float] = None,
                       block_q: int = 128, block_k: int = 128,
-                      interpret: bool = True):
+                      interpret: bool):
     """Flash backward for :func:`evo_attention_fwd`.
 
-    Consumes the saved fwd output + (L*H, S) log-sum-exp residuals; never
+    Consumes the saved fwd output + (L*H, 1, S) log-sum-exp residuals; never
     materializes an (S, S) probability matrix and never recomputes the
     forward softmax outside the tile being processed.  Returns
     ``(dq, dk, dv, dbias, dgate)`` in the public (L, S, H, C) / (H, S, S)
@@ -395,7 +405,8 @@ def evo_attention_bwd(q, k, v, bias, gate, out, lse, do, *,
     # in VMEM across the whole MSA-row reduction.
     dq, dgate, dbias = pl.pallas_call(
         functools.partial(_evo_bwd_dq_kernel, scale=scale, block_k=block_k,
-                          seq_k=s, biased=biased, gated=gated),
+                          seq_k=s, biased=biased, gated=gated,
+                          precision=contract_precision(q.dtype)),
         out_shape=[
             jax.ShapeDtypeStruct((lrows * h, s, c), q.dtype),
             dgate_shape,
@@ -410,8 +421,8 @@ def evo_attention_bwd(q, k, v, bias, gate, out, lse, do, *,
             gate_spec,
             blk_spec,                                              # out
             blk_spec,                                              # do
-            pl.BlockSpec((None, block_q),
-                         lambda hh, j, li, H=h: (li * H + hh, j)),  # lse
+            pl.BlockSpec((None, 1, block_q),
+                         lambda hh, j, li, H=h: (li * H + hh, 0, j)),  # lse
         ],
         out_specs=[blk_spec, dgate_spec, dbias_spec],
         interpret=interpret,
@@ -427,7 +438,8 @@ def evo_attention_bwd(q, k, v, bias, gate, out, lse, do, *,
                     else pl.BlockSpec((None, 1, 1), lambda *_: (0, 0, 0)))
     dk, dv = pl.pallas_call(
         functools.partial(_evo_bwd_dkv_kernel, scale=scale, block_q=block_q,
-                          seq_q=s, biased=biased, gated=gated),
+                          seq_q=s, biased=biased, gated=gated,
+                          precision=contract_precision(q.dtype)),
         out_shape=[
             jax.ShapeDtypeStruct((lrows * h, s, c), k.dtype),
             jax.ShapeDtypeStruct((lrows * h, s, c), v.dtype),
@@ -441,7 +453,7 @@ def evo_attention_bwd(q, k, v, bias, gate, out, lse, do, *,
             gate_spec_kv,
             full_spec,                                             # out
             full_spec,                                             # do
-            pl.BlockSpec((None, s), lambda i, kb: (i, 0)),         # lse
+            pl.BlockSpec((None, 1, s), lambda i, kb: (i, 0, 0)),   # lse
         ],
         out_specs=[
             pl.BlockSpec((None, block_k, c), lambda i, kb: (i, kb, 0)),

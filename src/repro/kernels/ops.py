@@ -1,6 +1,7 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-Forward = Pallas kernel (interpret mode on CPU, Mosaic on TPU).
+Forward = Pallas kernel: Mosaic on TPU, the interpreter elsewhere
+(``interpret_mode``).
 
 Backward:
 
@@ -27,15 +28,19 @@ from repro.kernels import triangle as tk
 from repro.nn.attention import attention_chunked
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+def interpret_mode() -> bool:
+    """Whether the Pallas kernels run in the interpreter: exactly when the
+    default backend is not a TPU.  The one place this is decided — every
+    kernel entry takes ``interpret`` as a required argument, so no caller
+    can reach the interpreter on the chip by omission."""
+    return jax.default_backend() != "tpu"
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(3, 4))
 def flash_attention(q, k, v, causal: bool = True,
                     scale: Optional[float] = None):
     return fk.flash_attention_fwd(q, k, v, causal=causal, scale=scale,
-                                  interpret=not _on_tpu())
+                                  interpret=interpret_mode())
 
 
 def _fa_fwd(q, k, v, causal, scale):
@@ -62,12 +67,12 @@ def evo_attention(q, k, v, bias, gate, scale: Optional[float] = None):
     via the flash backward kernels.
     """
     return fk.evo_attention_fwd(q, k, v, bias, gate, scale=scale,
-                                interpret=not _on_tpu())
+                                interpret=interpret_mode())
 
 
 def _ea_fwd(q, k, v, bias, gate, scale):
     out, lse = fk.evo_attention_fwd(q, k, v, bias, gate, scale=scale,
-                                    interpret=not _on_tpu(),
+                                    interpret=interpret_mode(),
                                     return_residuals=True)
     return out, (q, k, v, bias, gate, out, lse)
 
@@ -76,7 +81,7 @@ def _ea_bwd(scale, res, g):
     q, k, v, bias, gate, out, lse = res
     dq, dk, dv, dbias, dgate = fk.evo_attention_bwd(
         q, k, v, bias, gate, out, lse, g, scale=scale,
-        interpret=not _on_tpu())
+        interpret=interpret_mode())
     return dq, dk, dv, dbias, dgate
 
 
@@ -92,12 +97,12 @@ def evo_attention_nogate(q, k, v, bias, scale: Optional[float] = None):
     epilogue compiled out.
     """
     return fk.evo_attention_fwd(q, k, v, bias, None, scale=scale,
-                                interpret=not _on_tpu())
+                                interpret=interpret_mode())
 
 
 def _eang_fwd(q, k, v, bias, scale):
     out, lse = fk.evo_attention_fwd(q, k, v, bias, None, scale=scale,
-                                    interpret=not _on_tpu(),
+                                    interpret=interpret_mode(),
                                     return_residuals=True)
     return out, (q, k, v, bias, out, lse)
 
@@ -106,7 +111,7 @@ def _eang_bwd(scale, res, g):
     q, k, v, bias, out, lse = res
     dq, dk, dv, dbias, _ = fk.evo_attention_bwd(
         q, k, v, bias, None, out, lse, g, scale=scale,
-        interpret=not _on_tpu())
+        interpret=interpret_mode())
     return dq, dk, dv, dbias
 
 
@@ -119,12 +124,12 @@ def evo_attention_nobias(q, k, v, gate, scale: Optional[float] = None):
     ``evo_pallas``): the bias add is compiled out of the kernel — no zeros
     bias is materialized or streamed."""
     return fk.evo_attention_fwd(q, k, v, None, gate, scale=scale,
-                                interpret=not _on_tpu())
+                                interpret=interpret_mode())
 
 
 def _eanb_fwd(q, k, v, gate, scale):
     out, lse = fk.evo_attention_fwd(q, k, v, None, gate, scale=scale,
-                                    interpret=not _on_tpu(),
+                                    interpret=interpret_mode(),
                                     return_residuals=True)
     return out, (q, k, v, gate, out, lse)
 
@@ -133,7 +138,7 @@ def _eanb_bwd(scale, res, g):
     q, k, v, gate, out, lse = res
     dq, dk, dv, _, dgate = fk.evo_attention_bwd(
         q, k, v, None, gate, out, lse, g, scale=scale,
-        interpret=not _on_tpu())
+        interpret=interpret_mode())
     return dq, dk, dv, dgate
 
 
@@ -155,20 +160,20 @@ def triangle_mult(xa, xb, xg, w_a, b_a, w_b, b_b, ln_s, ln_b, w_o, b_o,
     fp32 contraction residual (no chunked-XLA recompute of the O(r³) op).
     """
     return tk.triangle_mult_fwd(xa, xb, xg, w_a, b_a, w_b, b_b, ln_s, ln_b,
-                                w_o, b_o, w_g, b_g, interpret=not _on_tpu())
+                                w_o, b_o, w_g, b_g, interpret=interpret_mode())
 
 
 def _tm_fwd(xa, xb, xg, w_a, b_a, w_b, b_b, ln_s, ln_b, w_o, b_o, w_g, b_g):
     out, s = tk.triangle_mult_fwd(
         xa, xb, xg, w_a, b_a, w_b, b_b, ln_s, ln_b, w_o, b_o, w_g, b_g,
-        interpret=not _on_tpu(), return_residuals=True)
+        interpret=interpret_mode(), return_residuals=True)
     return out, (xa, xb, xg, w_a, b_a, w_b, b_b, ln_s, ln_b, w_o, b_o,
                  w_g, b_g, s)
 
 
 def _tm_bwd(res, dy):
     xa, xb, xg, w_a, b_a, w_b, b_b, ln_s, ln_b, w_o, b_o, w_g, b_g, s = res
-    interpret = not _on_tpu()
+    interpret = interpret_mode()
     ds, dxg, dln_s, dln_b, dw_o, db_o, dw_g, db_g = \
         tk.triangle_mult_bwd_epilogue(s, xg, dy, ln_s, ln_b, w_o, b_o,
                                       w_g, b_g, interpret=interpret)
@@ -198,4 +203,4 @@ def triangle_mult_masked(xa, xb, xg, k_mask, w_a, b_a, w_b, b_b, ln_s, ln_b,
     """
     return tk.triangle_mult_fwd(xa, xb, xg, w_a, b_a, w_b, b_b, ln_s, ln_b,
                                 w_o, b_o, w_g, b_g, k_mask=k_mask,
-                                interpret=not _on_tpu())
+                                interpret=interpret_mode())

@@ -93,8 +93,6 @@ def batch_specs_tree(shapes, data_axes):
 
 def analyse(lowered, compiled, n_devices) -> dict:
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):  # older jax: one dict per device/computation
-        ca = ca[0] if ca else {}
     ma = compiled.memory_analysis()
     colls = parse_hlo_collectives(compiled.as_text())
     return {

@@ -14,15 +14,13 @@ from __future__ import annotations
 
 import os
 
-import jax
-
-from repro.parallel.mesh_utils import refactor_mesh
+from repro.parallel.mesh_utils import make_mesh, refactor_mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def production_mesh_from_env(multi_pod: bool = False,
@@ -33,7 +31,7 @@ def production_mesh_from_env(multi_pod: bool = False,
     if override:
         dims = tuple(int(x) for x in override.split("x"))
         axes = ("pod", "data", "model")[-len(dims):]
-        return jax.make_mesh(dims, axes)
+        return make_mesh(dims, axes)
     return make_production_mesh(multi_pod=multi_pod)
 
 

@@ -32,6 +32,8 @@ import time
 # slot in; these flags let the scheduler actually hide the transfer).
 # Emitted by --print-tpu-env; eval the output in the launch shell:
 #   eval "$(python -m repro.launch.train --print-tpu-env)"
+# libtpu 0.0.34 accepts every flag here (it refuses to start on an unknown
+# one).
 TPU_ASYNC_COLLECTIVE_FLAGS = (
     "--xla_tpu_enable_flash_attention=false",
     "--xla_tpu_enable_async_collective_fusion=true",
@@ -46,9 +48,14 @@ TPU_ASYNC_COLLECTIVE_FLAGS = (
 
 
 def print_tpu_env():
+    """Print a shell line that appends the preset to ``$LIBTPU_INIT_ARGS``:
+    flags the environment already sets (a machine may need its own) are
+    kept, never replaced."""
     print("# async collective fusion preset (overlapped-DAP schedule): "
           "eval this in the launch shell")
-    print(f"export LIBTPU_INIT_ARGS='{' '.join(TPU_ASYNC_COLLECTIVE_FLAGS)}'")
+    flags = " ".join(TPU_ASYNC_COLLECTIVE_FLAGS)
+    print('export LIBTPU_INIT_ARGS="${LIBTPU_INIT_ARGS:+$LIBTPU_INIT_ARGS }'
+          f'{flags}"')
 
 
 def main():
@@ -76,8 +83,9 @@ def main():
                          "pure-DAP 'parallel' groups, 'on'/'off' force it "
                          "(on is rejected for hybrid/serial plans)")
     ap.add_argument("--print-tpu-env", action="store_true",
-                    help="print the LIBTPU_INIT_ARGS async-collective-fusion "
-                         "preset (shell-eval'able) and exit")
+                    help="print a shell line appending the async-"
+                         "collective-fusion preset to $LIBTPU_INIT_ARGS "
+                         "(eval it) and exit")
     ap.add_argument("--lr", type=float, default=1e-3)
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--recycle-sample", action="store_true",
@@ -161,10 +169,9 @@ def main():
     import jax
     import jax.numpy as jnp
     import numpy as np
-    from repro.train.checkpoint import CheckpointManager, StepWatchdog
-    from repro.train.optim import adamw, af2_lr_schedule, warmup_cosine
-    from repro.data.loader import ShardedLoader
+    from repro.launch.compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     if args.af2:
         run_af2(args, jax, jnp, np)
     else:
@@ -341,7 +348,8 @@ def run_lm(args, jax, jnp, np):
            else cfglib.get_config(args.arch))
     model = get_model(cfg)
     n_dev = len(jax.devices())
-    mesh = jax.make_mesh((n_dev, 1), ("data", "model"))
+    from repro.parallel.mesh_utils import make_mesh
+    mesh = make_mesh((n_dev, 1), ("data", "model"))
     opt = adamw(warmup_cosine(args.lr, 20, args.steps), clip_norm=1.0)
     step_fn, state_shardings, batch_sharding = make_lm_train_step(
         model, cfg, opt, mesh)

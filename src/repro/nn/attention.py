@@ -149,7 +149,14 @@ def attention_chunked(q, k, v, *, causal: bool = False,
         if "mask" in x:
             valid = valid & x["mask"]
         logits = jnp.where(valid, logits, NEG_INF)
-        m_new = jnp.maximum(m, jnp.max(logits, axis=-1))
+        # the output does not depend on the running max (it cancels between
+        # acc and l), so no gradient flows through it.  Differentiating
+        # jnp.max instead divides by the count of entries equal to the max;
+        # on a TPU the recomputed logits can differ in the last bit from the
+        # ones the max was taken over (another fusion, excess precision),
+        # the count is 0, and 0/0 poisons dq and dk with NaN.
+        m_new = jax.lax.stop_gradient(
+            jnp.maximum(m, jnp.max(logits, axis=-1)))
         p = jnp.exp(logits - m_new[..., None])
         p = jnp.where(jnp.broadcast_to(valid, p.shape), p, 0.0)
         corr = jnp.exp(m - m_new)

@@ -39,6 +39,17 @@ F32 = Policy(compute_dtype=jnp.float32)
 BF16 = Policy()
 
 
+def randomize(params: Params, key, scale: float = 0.02) -> Params:
+    """Add ``scale`` x N(0, 1) noise to every leaf.  AF2 zero-inits its
+    residual outputs, so at init most of a block is invisible to a
+    comparison of two implementations; this makes it visible."""
+    leaves, treedef = jax.tree_util.tree_flatten(params)
+    keys = jax.random.split(key, len(leaves))
+    return jax.tree_util.tree_unflatten(treedef, [
+        leaf + scale * jax.random.normal(k, leaf.shape, leaf.dtype)
+        for leaf, k in zip(leaves, keys)])
+
+
 # ---------------------------------------------------------------------------
 # Linear / dense
 # ---------------------------------------------------------------------------
@@ -60,7 +71,13 @@ def dense_init(key, in_dim: int, out_dim: int, *, use_bias: bool = True,
 def dense(params: Params, x: jnp.ndarray) -> jnp.ndarray:
     y = x @ params["w"]
     if "b" in params:
-        y = y + params["b"]
+        b = params["b"]
+        # add in f32: the bias gradient is then summed over every leading
+        # row in f32, not in the compute dtype — a bf16 sum of that many
+        # terms cancels badly, and differs with how the rows are split
+        # across devices (DAP shards them)
+        out_dtype = jnp.result_type(y.dtype, b.dtype)
+        y = (y.astype(jnp.float32) + b.astype(jnp.float32)).astype(out_dtype)
     return y
 
 

@@ -165,9 +165,9 @@ class ProfileWindow:
 
     Call :meth:`maybe_start`/:meth:`maybe_stop` at each step boundary with
     the current step id; the device trace lands in ``logdir`` aligned to
-    the same step ids as the host spans.  Failures to start/stop (e.g. no
-    profiler support on the backend) degrade to a warning, never crash
-    the run.
+    the same step ids as the host spans.  A capture that fails to start or
+    stop raises: a run asked to trace that returns without its trace would
+    pass for a traced one.
     """
 
     def __init__(self, lo: int, hi: int, logdir: str, log=print):
@@ -179,32 +179,22 @@ class ProfileWindow:
     def maybe_start(self, step: int) -> None:
         if self.active or step != self.lo:
             return
-        try:
-            import jax
-            jax.profiler.start_trace(self.logdir)
-            self.active = True
-            self.log(f"[obs] jax.profiler capture ON at step {step} "
-                     f"-> {self.logdir}")
-        except Exception as e:  # pragma: no cover - backend dependent
-            self.log(f"[obs] jax.profiler start failed: {e}")
-            self.lo = -1  # don't retry
+        import jax
+        jax.profiler.start_trace(self.logdir)
+        self.active = True
+        self.log(f"[obs] jax.profiler capture ON at step {step} "
+                 f"-> {self.logdir}")
 
     def maybe_stop(self, step: int) -> None:
         if not self.active or step + 1 != self.hi:
             return
-        try:
-            import jax
-            jax.profiler.stop_trace()
-            self.log(f"[obs] jax.profiler capture OFF after step {step}")
-        except Exception as e:  # pragma: no cover - backend dependent
-            self.log(f"[obs] jax.profiler stop failed: {e}")
+        import jax
         self.active = False
+        jax.profiler.stop_trace()
+        self.log(f"[obs] jax.profiler capture OFF after step {step}")
 
     def close(self) -> None:
-        if self.active:  # pragma: no cover - abnormal exit path
-            try:
-                import jax
-                jax.profiler.stop_trace()
-            except Exception:
-                pass
+        if self.active:  # the run ended inside the window
+            import jax
             self.active = False
+            jax.profiler.stop_trace()

@@ -133,8 +133,7 @@ def dap_outer_product_mean(p, msa_l, n_seq_total: int = None,
     (r/d, r, c^2) outer tensor is never materialized.
     """
     if n_seq_total is None:
-        from repro.parallel.mesh_utils import axis_extent
-        n_seq_total = msa_l.shape[0] * axis_extent(axis_name)
+        n_seq_total = msa_l.shape[0] * jax.lax.axis_size(axis_name)
     h = nn.layernorm(p["ln"], msa_l)
     a = nn.dense(p["a"], h)                                    # (s/d, r, c)
     b = nn.dense(p["b"], h)

@@ -12,7 +12,7 @@ import math
 from typing import Mapping, Sequence
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 
 def refactor_mesh(mesh: Mesh, split: Mapping[str, Sequence[tuple[str, int]]]) -> Mesh:
@@ -53,33 +53,29 @@ def axis_size(mesh: Mesh, name: str) -> int:
     return mesh.shape[name] if name in mesh.axis_names else 1
 
 
+def make_mesh(shape: Sequence[int], names: Sequence[str], *,
+              devices=None) -> Mesh:
+    """``jax.make_mesh`` with every axis ``Auto``.
+
+    The repo's steps mix ``shard_map`` (AF2) with GSPMD
+    ``with_sharding_constraint`` on ``P('data', ...)`` (LM zoo); both need
+    Auto axes, and ``jax.make_mesh`` defaults to Explicit ones."""
+    return jax.make_mesh(tuple(shape), tuple(names),
+                         axis_types=(AxisType.Auto,) * len(shape),
+                         devices=devices)
+
+
 def smap(f, mesh: Mesh, in_specs, out_specs):
-    """``shard_map`` with replication-check off (BP's axis_index-dependent
-    branches are deliberately non-replicated mid-computation), compatible
-    across both the check_rep/check_vma rename and the
-    jax.experimental.shard_map -> jax.shard_map promotion."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm
-    try:
-        return sm(f, mesh=mesh, in_specs=in_specs,
-                  out_specs=out_specs, check_vma=False)
-    except TypeError:
-        return sm(f, mesh=mesh, in_specs=in_specs,
-                  out_specs=out_specs, check_rep=False)
-
-
-def axis_extent(axis_name: str) -> int:
-    """Static extent of a shard_map axis (works across jax versions)."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    # older jax: psum of a python int folds to the static axis size
-    return jax.lax.psum(1, axis_name)
+    """``jax.shard_map`` with the varying-manual-axes check off (BP's
+    axis_index-dependent branches are deliberately non-replicated
+    mid-computation)."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def local_slice(x, axis_name: str, dim: int):
     """Inside shard_map: take this device's equal slice of ``x`` along ``dim``."""
-    n = axis_extent(axis_name)
+    n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     size = x.shape[dim] // n
     return jax.lax.dynamic_slice_in_dim(x, idx * size, size, dim)

@@ -289,7 +289,7 @@ class ParallelPlan:
         return _build(self, mesh, cfg)
 
     def _make_mesh(self, devices: Sequence):
-        import jax
+        from repro.parallel.mesh_utils import make_mesh
         n = self.n_devices
         if len(devices) != n:
             raise PlanError(
@@ -308,7 +308,7 @@ class ParallelPlan:
         # axis carries ~13 collectives per block — it must sit on adjacent
         # chips); a raw Mesh(devices.reshape(...)) would keep enumeration
         # order
-        return jax.make_mesh(shape, names, devices=list(devices))
+        return make_mesh(shape, names, devices=list(devices))
 
     def _adapt_mesh(self, mesh):
         """Fit the plan onto a production mesh (pod?, data, model): the

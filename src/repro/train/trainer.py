@@ -137,12 +137,13 @@ class TrainRunner:
         self._lddt = None
 
         params = af2.init_params(jax.random.PRNGKey(seed), cfg)
-        self.state = {"params": params, "opt": self.optimizer.init(params)}
+        state = {"params": params, "opt": self.optimizer.init(params)}
         if self.ema is not None:
-            self.state["ema"] = self.ema.init(params)
+            state["ema"] = self.ema.init(params)
         if base_plan.compress_pod_grads:
             from repro.parallel.grad_sync import zeros_error_state
-            self.state["err"] = zeros_error_state(params)
+            state["err"] = zeros_error_state(params)
+        self.state = self._place(state)
         self.step = 0
         self.mgr = (CheckpointManager(ckpt_dir, keep=keep,
                                       install_sigterm=install_sigterm,
@@ -156,6 +157,16 @@ class TrainRunner:
         self.history = {k: self.obs.series(f"train/{k}") for k in
                         ("loss", "n_recycle", "step_s", "eval", "data",
                          "attribution")}
+
+    def _place(self, state):
+        """Commit ``state`` to the replicated sharding the step returns it
+        with.  Fresh arrays are uncommitted single-device ones, and jit keys
+        its cache on sharding: without this the first and second calls
+        would trace two programs."""
+        import jax
+        from jax.sharding import NamedSharding
+        return jax.device_put(
+            state, NamedSharding(self.built.mesh, self.built.state_spec))
 
     # -- compile accounting (the FoldEngine contract, training-side) --------
 
@@ -268,8 +279,9 @@ class TrainRunner:
         cross-checked against this runner's plan fingerprint."""
         if self.mgr is None:
             raise ValueError("TrainRunner has no ckpt_dir; nothing to restore")
-        self.state, self.step = self.mgr.restore_latest(
+        state, self.step = self.mgr.restore_latest(
             self.state, adapt_plan=adapt_plan)
+        self.state = self._place(state)
         return self.step
 
     # -- the input pipeline --------------------------------------------------
@@ -308,7 +320,9 @@ class TrainRunner:
         recorded into ``history["attribution"]`` (see obs.attribution)."""
         from repro.obs import attribution_report
         rep = attribution_report(
-            self.cfg, self.plan, global_batch=self.batch_size,
+            self.cfg, self.plan,
+            device_kind=self.built.mesh.devices.flat[0].device_kind,
+            global_batch=self.batch_size,
             n_recycle=n_recycle, measured_step_s=measured_step_s,
             stall_fraction=stall_fraction, overhead_s=overhead_s,
             wall_s=wall_s, step=step)
@@ -320,20 +334,18 @@ class TrainRunner:
         lower the RAW train step (uncounted, undonated — ``train_compiles``
         stays 1), inspect the optimized HLO for hidden collectives, record
         the verdict (or the skip reason: CPU backends don't split
-        collectives into start/done pairs) as ``train/async_overlap_ok``."""
+        collectives into start/done pairs) as ``train/async_overlap_ok``.
+        A step that fails to lower raises: it could not train either."""
         import jax
         from repro.analysis.hlo import check_async_overlap
-        try:
-            txt = (jax.jit(self._raw_step)
-                   .lower(self.state, batch, jax.random.PRNGKey(0),
-                          self.max_recycle if self.recycle_sample else None)
-                   .compile().as_text())
-            ok, rep = check_async_overlap(txt)
-        except Exception as e:  # keep training even if lowering fails
-            ok, rep = None, {"error": f"{type(e).__name__}: {e}"}
+        txt = (jax.jit(self._raw_step)
+               .lower(self.state, batch, jax.random.PRNGKey(0),
+                      self.max_recycle if self.recycle_sample else None)
+               .compile().as_text())
+        ok, rep = check_async_overlap(txt)
         row = {"ok": ok, "skipped": ok is None,
-               "reason": (None if ok is not None else rep.get(
-                   "error", "no async collective start/done pairs in HLO"))}
+               "reason": (None if ok is not None else
+                          "no async collective start/done pairs in HLO")}
         for k in ("pairs", "overlapped", "exposed"):
             if k in rep:
                 row[k] = rep[k]

@@ -1,4 +1,6 @@
 """Chunked flash-style attention vs naive reference (+ hypothesis sweep)."""
+from functools import partial
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -173,6 +175,55 @@ def test_chunked_bias_is_not_broadcast_upfront():
     ref1 = attention_reference(q, k, v, bias=b1)
     np.testing.assert_allclose(np.asarray(out1), np.asarray(ref1),
                                rtol=2e-5, atol=2e-5)
+
+
+def _eq_against_reduce_max(closed_jaxpr) -> int:
+    """Count `eq` eqns comparing against a reduce_max output (through
+    shape-only ops): the location mask of jnp.max's VJP."""
+    from jax.extend.core import Var
+    from repro.analysis.static.jaxpr_walk import iter_eqns
+    eqns = [e for e, _ in iter_eqns(closed_jaxpr)]
+    producer = {v: e for e in eqns for v in e.outvars}
+    passthrough = {"broadcast_in_dim", "reshape", "convert_element_type",
+                   "squeeze", "expand_dims"}
+
+    def from_max(v):
+        e = producer.get(v) if isinstance(v, Var) else None
+        while e is not None and e.primitive.name in passthrough:
+            v = e.invars[0]
+            e = producer.get(v) if isinstance(v, Var) else None
+        return e is not None and e.primitive.name == "reduce_max"
+
+    return sum(any(from_max(v) for v in e.invars)
+               for e in eqns if e.primitive.name == "eq")
+
+
+def test_chunked_grad_does_not_differentiate_running_max():
+    """The running max cancels out of the output, so no gradient may flow
+    through it.  jnp.max's VJP divides by the count of logits equal to the
+    max; when recomputed logits differ in the last bit from the ones the
+    max saw (a TPU can fuse the two apart), that count is 0 and dq/dk
+    become 0/0 = NaN.  The gradients must still be exact."""
+    ks = jax.random.split(jax.random.PRNGKey(3), 4)
+    q = jax.random.normal(ks[0], (2, 12, 4, 8))
+    k = jax.random.normal(ks[1], (2, 12, 4, 8))
+    v = jax.random.normal(ks[2], (2, 12, 4, 8))
+    bias = jax.random.normal(ks[3], (4, 12, 12))
+
+    def loss(fn, q, k, v, b):
+        return (fn(q, k, v, bias=b) ** 2).sum()
+
+    grad = jax.grad(partial(loss, partial(attention_chunked, chunk_size=4)),
+                    argnums=(0, 1, 2, 3))
+    assert _eq_against_reduce_max(jax.make_jaxpr(grad)(q, k, v, bias)) == 0
+    # the check sees the pattern where it exists
+    ref_loss = lambda q: (jnp.max(q, axis=-1) ** 2).sum()
+    assert _eq_against_reduce_max(jax.make_jaxpr(jax.grad(ref_loss))(q)) == 1
+    want = jax.grad(partial(loss, attention_reference),
+                    argnums=(0, 1, 2, 3))(q, k, v, bias)
+    for g, w in zip(grad(q, k, v, bias), want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   rtol=1e-4, atol=1e-4)
 
 
 def test_rope_preserves_norm_and_relative_phase():

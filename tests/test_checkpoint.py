@@ -61,7 +61,8 @@ def test_elastic_reshard_restore(tmp_path):
     from jax.sharding import NamedSharding, PartitionSpec as P
     tree = _tree()
     ck.save_checkpoint(tmp_path, 2, tree)
-    mesh = jax.make_mesh((1,), ("data",))
+    from repro.parallel.mesh_utils import make_mesh
+    mesh = make_mesh((1,), ("data",))
     sh = jax.tree_util.tree_map(
         lambda _: NamedSharding(mesh, P()), tree)
     restored, _ = ck.restore_checkpoint(tmp_path, tree, shardings=sh)

@@ -154,8 +154,9 @@ def test_evo_block_size_always_divides():
     L, s, h, c = 2, 128, 2, 16
     q, k, v, gate = (jax.random.normal(kk, (L, s, h, c)) for kk in ks[:4])
     bias = jax.random.normal(ks[4], (h, s, s))
-    a = evo_attention_fwd(q, k, v, bias, gate, block_q=96, block_k=96)
-    b = evo_attention_fwd(q, k, v, bias, gate)
+    a = evo_attention_fwd(q, k, v, bias, gate, block_q=96, block_k=96,
+                          interpret=True)
+    b = evo_attention_fwd(q, k, v, bias, gate, interpret=True)
     assert np.isfinite(np.asarray(a)).all()
     np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                rtol=2e-5, atol=2e-5)
@@ -186,8 +187,8 @@ def test_evo_fwd_residuals_lse():
     ks = jax.random.split(jax.random.PRNGKey(14), 5)
     q, k, v, gate = (jax.random.normal(kk, (L, s, h, c)) for kk in ks[:4])
     bias = jax.random.normal(ks[4], (h, s, s))
-    out0 = fk.evo_attention_fwd(q, k, v, bias, gate)
-    out1, lse = fk.evo_attention_fwd(q, k, v, bias, gate,
+    out0 = fk.evo_attention_fwd(q, k, v, bias, gate, interpret=True)
+    out1, lse = fk.evo_attention_fwd(q, k, v, bias, gate, interpret=True,
                                      return_residuals=True)
     np.testing.assert_allclose(np.asarray(out0), np.asarray(out1))
     scale = c ** -0.5
@@ -205,7 +206,9 @@ def test_kernel_blocking_invariance():
     q = jax.random.normal(ks[0], (1, 256, 2, 64))
     k = jax.random.normal(ks[1], (1, 256, 2, 64))
     v = jax.random.normal(ks[2], (1, 256, 2, 64))
-    a = flash_attention_fwd(q, k, v, causal=True, block_q=128, block_k=128)
-    b = flash_attention_fwd(q, k, v, causal=True, block_q=64, block_k=32)
+    a = flash_attention_fwd(q, k, v, causal=True, block_q=128, block_k=128,
+                            interpret=True)
+    b = flash_attention_fwd(q, k, v, causal=True, block_q=64, block_k=32,
+                            interpret=True)
     np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                rtol=2e-5, atol=2e-5)

@@ -221,8 +221,7 @@ def test_f32_accumulation_stays_clean():
 
 
 def test_f64_fixture_fires():
-    from jax.experimental import enable_x64
-    with enable_x64():
+    with jax.enable_x64(True):
         jx = jax.make_jaxpr(lambda x: jnp.sum(x * 2.0))(
             jax.ShapeDtypeStruct((4,), jnp.float64))
     res = PrecisionPass().run(_fixture("f64", {"fwd": jx},

@@ -217,7 +217,8 @@ def test_parse_profile_steps():
 def test_attribution_report_fields_and_bounds():
     cfg = _cfg()
     rep = attribution_report(
-        cfg, ParallelPlan(), global_batch=2, n_recycle=2.0,
+        cfg, ParallelPlan(), device_kind="TPU v5 lite", global_batch=2,
+        n_recycle=2.0,
         measured_step_s=0.5, stall_fraction=0.1, overhead_s=1.0,
         wall_s=10.0, step=7)
     assert rep["step"] == 7
@@ -232,6 +233,22 @@ def test_attribution_report_fields_and_bounds():
     assert "ParallelPlan" in rep["plan"]
     line = describe_attribution(rep)
     assert "MFU" in line and "goodput" in line and "stall" in line
+
+
+def test_attribution_mfu_needs_a_known_device():
+    """MFU divides by the measured device's published peak: the host CPU
+    has none ("not measured"), and an unknown accelerator raises."""
+    cfg = _cfg()
+    kw = dict(global_batch=2, n_recycle=1.0, measured_step_s=0.5)
+    v5e = attribution_report(cfg, ParallelPlan(), device_kind="TPU v5 lite",
+                             **kw)
+    assert v5e["mfu"] == pytest.approx(
+        v5e["achieved_flops"] / 197e12)
+    cpu = attribution_report(cfg, ParallelPlan(), device_kind="cpu", **kw)
+    assert cpu["mfu"] is None
+    assert "MFU not measured" in describe_attribution(cpu)
+    with pytest.raises(KeyError, match="no published peaks"):
+        attribution_report(cfg, ParallelPlan(), device_kind="TPU v99", **kw)
 
 
 def test_predict_step_time_scales_with_batch_and_recycle():

@@ -24,16 +24,11 @@ from repro.core.config import af2_tiny
 from repro.core import model as af2
 from repro.parallel import dap as dap_lib
 from repro.parallel.branch import bp_evoformer_block, bp_dap_evoformer_block
-from repro.parallel.mesh_utils import smap
+from repro.parallel.mesh_utils import make_mesh, smap
 
 cfg = af2_tiny(variant="parallel")
 ev = cfg.evoformer
-def randomize(params, key):
-    leaves, treedef = jax.tree_util.tree_flatten(params)
-    keys = jax.random.split(key, len(leaves))
-    return jax.tree_util.tree_unflatten(treedef, [
-        l + 0.02 * jax.random.normal(k, l.shape, l.dtype)
-        for l, k in zip(leaves, keys)])
+from repro.nn.layers import randomize
 
 params = randomize(af2.stack_init(jax.random.PRNGKey(0), ev, 2, scan=True),
                    jax.random.PRNGKey(7))
@@ -44,7 +39,7 @@ ref_msa, ref_z = jax.jit(lambda p, m, zz: af2.evoformer_stack(
     p, ev, 2, m, zz, scan=True, remat=False))(params, msa, z)
 
 # BP=2
-mesh = jax.make_mesh((2,), ("branch",))
+mesh = make_mesh((2,), ("branch",))
 bp = jax.jit(smap(lambda p, m, zz: af2.evoformer_stack(
     p, ev, 2, m, zz, scan=True, remat=False, block_fn=bp_evoformer_block),
     mesh, (P(), P(), P()), (P(), P())))
@@ -57,7 +52,7 @@ print("BP ok")
 ev_af2 = af2_tiny(variant="af2").evoformer
 ra, rz = jax.jit(lambda p, m, zz: af2.evoformer_stack(
     p, ev_af2, 2, m, zz, scan=True, remat=False))(params, msa, z)
-mesh = jax.make_mesh((4,), ("dap",))
+mesh = make_mesh((4,), ("dap",))
 def dap_stack(p, m, zz):
     m_l, z_l = dap_lib.shard_inputs(m, zz)
     m_l, z_l = af2.evoformer_stack(p, ev_af2, 2, m_l, z_l, scan=True,
@@ -70,7 +65,7 @@ np.testing.assert_allclose(np.asarray(rz), np.asarray(dz), rtol=3e-4, atol=3e-4)
 print("DAP ok")
 
 # hybrid BP=2 x DAP=2 x data=2, with gradients
-mesh = jax.make_mesh((2, 2, 2), ("data", "branch", "dap"))
+mesh = make_mesh((2, 2, 2), ("data", "branch", "dap"))
 def hybrid_stack(p, m, zz):
     m_l, z_l = dap_lib.shard_inputs(m, zz)
     def bf(bp_, c, mm, zzz, rng=None, deterministic=True):
@@ -105,16 +100,11 @@ from repro.core.config import af2_tiny
 from repro.core import model as af2
 from repro.parallel import dap as dap_lib
 from repro.parallel.branch import bp_evoformer_block
-from repro.parallel.mesh_utils import smap
+from repro.parallel.mesh_utils import make_mesh, smap
 
 cfg = af2_tiny(variant="parallel", attention_impl="evo_pallas")
 ev = cfg.evoformer
-def randomize(params, key):
-    leaves, treedef = jax.tree_util.tree_flatten(params)
-    keys = jax.random.split(key, len(leaves))
-    return jax.tree_util.tree_unflatten(treedef, [
-        l + 0.02 * jax.random.normal(k, l.shape, l.dtype)
-        for l, k in zip(leaves, keys)])
+from repro.nn.layers import randomize
 params = randomize(af2.stack_init(jax.random.PRNGKey(0), ev, 1, scan=True),
                    jax.random.PRNGKey(7))
 s, r = cfg.n_seq, cfg.n_res
@@ -123,7 +113,7 @@ z = jax.random.normal(jax.random.PRNGKey(2), (r, r, ev.c_z))
 ref_m, ref_z = jax.jit(lambda p, m, zz: af2.evoformer_stack(
     p, ev, 1, m, zz, scan=True, remat=False))(params, msa, z)
 
-mesh = jax.make_mesh((2,), ("branch",))
+mesh = make_mesh((2,), ("branch",))
 bm, bz = jax.jit(smap(lambda p, m, zz: af2.evoformer_stack(
     p, ev, 1, m, zz, scan=True, remat=False, block_fn=bp_evoformer_block),
     mesh, (P(), P(), P()), (P(), P())))(params, msa, z)
@@ -131,7 +121,7 @@ np.testing.assert_allclose(np.asarray(ref_m), np.asarray(bm), rtol=2e-4, atol=2e
 np.testing.assert_allclose(np.asarray(ref_z), np.asarray(bz), rtol=2e-4, atol=2e-4)
 print("BP evo_pallas ok")
 
-mesh = jax.make_mesh((2,), ("dap",))
+mesh = make_mesh((2,), ("dap",))
 def dap_stack(p, m, zz):
     m_l, z_l = dap_lib.shard_inputs(m, zz)
     m_l, z_l = af2.evoformer_stack(p, ev, 1, m_l, z_l, scan=True, remat=False,
@@ -175,12 +165,12 @@ from jax.sharding import PartitionSpec as P
 from repro.core.config import af2_tiny
 from repro.core import model as af2
 from repro.parallel import dap as dap_lib
-from repro.parallel.mesh_utils import smap
+from repro.parallel.mesh_utils import make_mesh, smap
 from tests.util import count_prims, randomize
 
 cfg = af2_tiny(variant="parallel")
 s, r = cfg.n_seq, cfg.n_res
-mesh = jax.make_mesh((2,), ("dap",))
+mesh = make_mesh((2,), ("dap",))
 
 # --- per-block collective counts (prefetch passed as an input so the count
 # reflects steady-state blocks; the one-off seed gather lives in the stack) --
@@ -308,9 +298,9 @@ def test_grad_compression_error_feedback():
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
 from repro.parallel.grad_sync import compressed_psum_tree, zeros_error_state
-from repro.parallel.mesh_utils import smap
+from repro.parallel.mesh_utils import make_mesh, smap
 
-mesh = jax.make_mesh((4,), ("pod",))
+mesh = make_mesh((4,), ("pod",))
 g = {"w": jax.random.normal(jax.random.PRNGKey(0), (64,)),
      "b": jax.random.normal(jax.random.PRNGKey(1), (8,)) * 1e-3}
 
@@ -343,7 +333,7 @@ import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
 from repro.models import dense
 from repro.models.lmconfig import LMConfig
-from repro.parallel.mesh_utils import smap
+from repro.parallel.mesh_utils import make_mesh, smap
 
 cfg = LMConfig(arch_id="t", family="dense", n_layer=1, d_model=64, n_head=4,
                n_kv_head=2, d_ff=128, vocab=64, parallel_block=True,
@@ -353,7 +343,7 @@ x = jax.random.normal(jax.random.PRNGKey(1), (2, 12, 64))
 pos = jnp.broadcast_to(jnp.arange(12), (2, 12))
 ref, _ = dense.layer_apply(p, cfg, x, pos)
 
-mesh = jax.make_mesh((2,), ("branch",))
+mesh = make_mesh((2,), ("branch",))
 bp = jax.jit(smap(lambda p, x: dense.bp_parallel_layer(p, cfg, x, pos)[0],
                   mesh, (P(), P()), P()))
 out = bp(p, x)
@@ -376,8 +366,8 @@ print("dense BP parallel-block ok")
 def test_refactor_mesh_axes():
     run_subprocess("""
 import jax
-from repro.parallel.mesh_utils import refactor_mesh
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+from repro.parallel.mesh_utils import make_mesh, refactor_mesh
+mesh = make_mesh((2, 4), ("data", "model"))
 m2 = refactor_mesh(mesh, {"model": [("branch", 2), ("dap", 2)]})
 assert m2.axis_names == ("data", "branch", "dap"), m2.axis_names
 assert dict(m2.shape) == {"data": 2, "branch": 2, "dap": 2}

@@ -242,7 +242,8 @@ def test_build_hybrid_mesh_axes():
 
 @needs_8_devices
 def test_build_refactors_production_model_axis():
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    from repro.parallel.mesh_utils import make_mesh
+    mesh = make_mesh((2, 4), ("data", "model"))
     plan = ParallelPlan.for_mesh(mesh, branch=2, dap=2)
     built = plan.build(mesh, cfg=af2_tiny())
     assert dict(built.mesh.shape) == {"data": 2, "branch": 2, "dap": 2}

@@ -122,10 +122,12 @@ def test_predict_early_exit_freezes_converged_sample():
     the two samples, and checks predict() realizes exactly that schedule.
     """
     cfg = af2_tiny()
+    # seeds whose two samples take different recycle schedules under JAX's
+    # default (partitionable) threefry stream
     params = randomize(af2.init_params(jax.random.PRNGKey(0), cfg),
-                       jax.random.PRNGKey(1), scale=0.1)
-    sa = _infer_feats(protein_sample(jax.random.PRNGKey(21), cfg), cfg)
-    sb = _infer_feats(protein_sample(jax.random.PRNGKey(22), cfg), cfg)
+                       jax.random.PRNGKey(2), scale=0.1)
+    sa = _infer_feats(protein_sample(jax.random.PRNGKey(11), cfg), cfg)
+    sb = _infer_feats(protein_sample(jax.random.PRNGKey(12), cfg), cfg)
     batch = _batchify(sa, sb)
 
     # reference trajectory: fixed-recycle coords after k = 1, 2, 3 cycles

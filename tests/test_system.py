@@ -27,6 +27,8 @@ def test_train_af2_tiny_end_to_end(tmp_path):
                 "--batch", "2", "--ckpt-dir", str(tmp_path / "ck"),
                 "--ckpt-every", "2"])
     assert "done: 3 steps" in out
+    # no chip, no peak: MFU is not taken against a CPU time
+    assert "MFU not measured (cpu)" in out
     # checkpoint written and resumable
     out2 = _run(["repro.launch.train", "--af2", "tiny", "--steps", "4",
                  "--batch", "2", "--ckpt-dir", str(tmp_path / "ck"),
@@ -52,3 +54,18 @@ def test_serve_smoke():
                 "--requests", "3", "--slots", "2", "--max-new", "4",
                 "--prompt-len", "8", "--max-len", "32"])
     assert "served 3 requests" in out
+
+
+def test_print_tpu_env_appends_to_existing_args():
+    """The emitted line keeps what $LIBTPU_INIT_ARGS already holds."""
+    from repro.launch.train import TPU_ASYNC_COLLECTIVE_FLAGS
+    line = _run(["repro.launch.train", "--print-tpu-env"])
+    script = 'LIBTPU_INIT_ARGS="--keep_me=1"\n' + line + \
+        'printf %s "$LIBTPU_INIT_ARGS"'
+    got = subprocess.run(["bash", "-c", script], capture_output=True,
+                         text=True, check=True).stdout
+    assert got == " ".join(("--keep_me=1",) + TPU_ASYNC_COLLECTIVE_FLAGS)
+    alone = subprocess.run(["bash", "-c", "unset LIBTPU_INIT_ARGS\n" + line
+                            + 'printf %s "$LIBTPU_INIT_ARGS"'],
+                           capture_output=True, text=True, check=True).stdout
+    assert alone == " ".join(TPU_ASYNC_COLLECTIVE_FLAGS)

@@ -13,7 +13,8 @@ def _setup(microbatch=None):
     cfg = LMConfig(arch_id="t", family="dense", n_layer=2, d_model=32,
                    n_head=2, n_kv_head=2, d_ff=64, vocab=67,
                    scan_layers=True, remat="none", attention_chunk=8)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    from repro.parallel.mesh_utils import make_mesh
+    mesh = make_mesh((1, 1), ("data", "model"))
     opt = sgd(0.1)
     step, state_sh, batch_sh = make_lm_train_step(
         dense, cfg, opt, mesh, microbatch=microbatch)
@@ -49,7 +50,8 @@ def test_microbatch_equals_full_batch():
 
 def test_sanitize_spec_drops_indivisible():
     from jax.sharding import PartitionSpec as P
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    from repro.parallel.mesh_utils import make_mesh
+    mesh = make_mesh((1, 1), ("data", "model"))
 
     class FakeMesh:
         shape = {"data": 16, "model": 16}

@@ -89,8 +89,9 @@ def test_pallas_residual_fwd_consistent():
     args = (x, x, x, w_a, b_a, w_b, b_b, p["ln_out"]["scale"],
             p["ln_out"]["bias"], p["out"]["w"], p["out"]["b"],
             p["gate"]["w"], p["gate"]["b"])
-    out0 = tk.triangle_mult_fwd(*args)
-    out1, s = tk.triangle_mult_fwd(*args, return_residuals=True)
+    out0 = tk.triangle_mult_fwd(*args, interpret=True)
+    out1, s = tk.triangle_mult_fwd(*args, interpret=True,
+                                   return_residuals=True)
     np.testing.assert_allclose(np.asarray(out0), np.asarray(out1))
     a = jax.nn.sigmoid(nn.dense(p["a_gate"], x)) * nn.dense(p["a"], x)
     b = jax.nn.sigmoid(nn.dense(p["b_gate"], x)) * nn.dense(p["b"], x)

@@ -3,17 +3,7 @@ import subprocess
 import sys
 import textwrap
 
-import jax
-
-
-def randomize(params, key, scale=0.02):
-    """Replace AF2's zero-inits with small noise so equivalence tests are
-    non-vacuous (at init all residual updates are exactly zero)."""
-    leaves, treedef = jax.tree_util.tree_flatten(params)
-    keys = jax.random.split(key, len(leaves))
-    new = [l + scale * jax.random.normal(k, l.shape, l.dtype)
-           for l, k in zip(leaves, keys)]
-    return jax.tree_util.tree_unflatten(treedef, new)
+from repro.nn.layers import randomize  # noqa: F401  (re-exported for tests)
 
 
 def run_subprocess(code: str, *, devices: int = 8, timeout: int = 560) -> str:
