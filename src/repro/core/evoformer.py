@@ -531,24 +531,32 @@ def msa_branch(p: Params, cfg: EvoformerConfig, msa: jnp.ndarray,
     res_mask = rows_mask = None
     if masks is not None:
         rows_mask, res_mask = masks.rows, masks.res
-    upd = gated_attention(p["row_attn"], msa, n_head=cfg.n_head_msa,
-                          c_hidden=cfg.c_hidden_att, bias_input=z_bias_src,
-                          key_mask=res_mask, **kw)
-    if rng is not None:
-        rng, k = jax.random.split(rng)
-        upd = shared_dropout(k, upd, cfg.dropout_msa, shared_axis=0,
-                             deterministic=deterministic)
-    msa = msa + upd
-    if cfg.global_column_attn:
-        col = global_attention(p["col_attn"], msa.swapaxes(0, 1),
-                               n_head=cfg.n_head_msa, c_hidden=cfg.c_hidden_att,
-                               key_mask=rows_mask)
-    else:
-        col = gated_attention(p["col_attn"], msa.swapaxes(0, 1),
-                              n_head=cfg.n_head_msa, c_hidden=cfg.c_hidden_att,
-                              key_mask=rows_mask, **kw)
-    msa = msa + col.swapaxes(0, 1)
-    msa = msa + transition(p["msa_trans"], msa)
+    # named scopes (``jax.named_scope``) put each sub-op's name, with its
+    # dropout and residual add, into the op_name of every XLA op it lowers
+    # to: a profile of the step can then be read by sub-op
+    with jax.named_scope("msa_row_attn"):
+        upd = gated_attention(p["row_attn"], msa, n_head=cfg.n_head_msa,
+                              c_hidden=cfg.c_hidden_att,
+                              bias_input=z_bias_src, key_mask=res_mask, **kw)
+        if rng is not None:
+            rng, k = jax.random.split(rng)
+            upd = shared_dropout(k, upd, cfg.dropout_msa, shared_axis=0,
+                                 deterministic=deterministic)
+        msa = msa + upd
+    with jax.named_scope("msa_col_attn"):
+        if cfg.global_column_attn:
+            col = global_attention(p["col_attn"], msa.swapaxes(0, 1),
+                                   n_head=cfg.n_head_msa,
+                                   c_hidden=cfg.c_hidden_att,
+                                   key_mask=rows_mask)
+        else:
+            col = gated_attention(p["col_attn"], msa.swapaxes(0, 1),
+                                  n_head=cfg.n_head_msa,
+                                  c_hidden=cfg.c_hidden_att,
+                                  key_mask=rows_mask, **kw)
+        msa = msa + col.swapaxes(0, 1)
+    with jax.named_scope("msa_transition"):
+        msa = msa + transition(p["msa_trans"], msa)
     return msa
 
 
@@ -571,19 +579,26 @@ def pair_branch(p: Params, cfg: EvoformerConfig, z: jnp.ndarray, *, rng=None,
         return shared_dropout(k, x, cfg.dropout_pair, shared_axis=shared_axis,
                               deterministic=deterministic)
 
-    z = z + drop(0, tri_mult_apply(p["tri_mul_out"], cfg, z, outgoing=True,
-                                   k_mask=res_mask), 0)
-    z = z + drop(1, tri_mult_apply(p["tri_mul_in"], cfg, z, outgoing=False,
-                                   k_mask=res_mask), 0)
-    z = z + drop(2, gated_attention(p["tri_att_start"], z, n_head=cfg.n_head_pair,
-                                    c_hidden=cfg.c_hidden_pair_att,
-                                    bias_input=z, key_mask=res_mask, **kw), 0)
-    zt = z.swapaxes(0, 1)
-    att_end = gated_attention(p["tri_att_end"], zt, n_head=cfg.n_head_pair,
-                              c_hidden=cfg.c_hidden_pair_att, bias_input=zt,
-                              key_mask=res_mask, **kw)
-    z = z + drop(3, att_end.swapaxes(0, 1), 1)
-    z = z + transition(p["pair_trans"], z)
+    with jax.named_scope("tri_mult_out"):
+        z = z + drop(0, tri_mult_apply(p["tri_mul_out"], cfg, z,
+                                       outgoing=True, k_mask=res_mask), 0)
+    with jax.named_scope("tri_mult_in"):
+        z = z + drop(1, tri_mult_apply(p["tri_mul_in"], cfg, z,
+                                       outgoing=False, k_mask=res_mask), 0)
+    with jax.named_scope("tri_attn_start"):
+        z = z + drop(2, gated_attention(
+            p["tri_att_start"], z, n_head=cfg.n_head_pair,
+            c_hidden=cfg.c_hidden_pair_att, bias_input=z, key_mask=res_mask,
+            **kw), 0)
+    with jax.named_scope("tri_attn_end"):
+        zt = z.swapaxes(0, 1)
+        att_end = gated_attention(p["tri_att_end"], zt,
+                                  n_head=cfg.n_head_pair,
+                                  c_hidden=cfg.c_hidden_pair_att,
+                                  bias_input=zt, key_mask=res_mask, **kw)
+        z = z + drop(3, att_end.swapaxes(0, 1), 1)
+    with jax.named_scope("pair_transition"):
+        z = z + transition(p["pair_trans"], z)
     return z
 
 
@@ -601,12 +616,14 @@ def evoformer_block(p: Params, cfg: EvoformerConfig, msa: jnp.ndarray,
     if cfg.variant == "af2":
         msa_out = msa_branch(p, cfg, msa, z, rng=rngs[0],
                              deterministic=deterministic, masks=masks)
-        z = z + opm_apply(p["opm"], cfg, msa_out, row_mask=row_mask)
+        with jax.named_scope("opm"):
+            z = z + opm_apply(p["opm"], cfg, msa_out, row_mask=row_mask)
         z_out = pair_branch(p, cfg, z, rng=rngs[1], deterministic=deterministic,
                             masks=masks)
         return msa_out, z_out
     if cfg.variant == "multimer":
-        z = z + opm_apply(p["opm"], cfg, msa, row_mask=row_mask)
+        with jax.named_scope("opm"):
+            z = z + opm_apply(p["opm"], cfg, msa, row_mask=row_mask)
         msa_out = msa_branch(p, cfg, msa, z, rng=rngs[0],
                              deterministic=deterministic, masks=masks)
         z_out = pair_branch(p, cfg, z, rng=rngs[1], deterministic=deterministic,
@@ -619,7 +636,9 @@ def evoformer_block(p: Params, cfg: EvoformerConfig, msa: jnp.ndarray,
                              deterministic=deterministic, masks=masks)
         z_out = pair_branch(p, cfg, z, rng=rngs[1], deterministic=deterministic,
                             masks=masks)
-        z_out = z_out + opm_apply(p["opm"], cfg, msa_out, row_mask=row_mask)
+        with jax.named_scope("opm"):
+            z_out = z_out + opm_apply(p["opm"], cfg, msa_out,
+                                      row_mask=row_mask)
         return msa_out, z_out
     raise ValueError(f"unknown Evoformer variant {cfg.variant!r}")
 

@@ -214,8 +214,9 @@ def run_trunk(params, cfg: AlphaFold2Config, batch, prev, *, block_fn=None,
     every layout (DAP shards queries, never keys), so the same masks work
     for serial and dap block_fns.
     """
-    msa, z, extra = embed_inputs(params["embedder"], cfg, batch, dtype)
-    msa, z = embed_recycle(params["embedder"], cfg, msa, z, prev)
+    with jax.named_scope("embed"):
+        msa, z, extra = embed_inputs(params["embedder"], cfg, batch, dtype)
+        msa, z = embed_recycle(params["embedder"], cfg, msa, z, prev)
     pre, post = stack_io or ((lambda m, zz: (m, zz)),) * 2
     extra_masks = main_masks = None
     if masks is not None:
@@ -232,20 +233,25 @@ def run_trunk(params, cfg: AlphaFold2Config, batch, prev, *, block_fn=None,
     if rng is not None:
         rng, k1, k2 = jax.random.split(rng, 3)
     extra_l, z_l = pre(extra, z)
-    _, z_l = evoformer_stack(params["extra_stack"], cfg.extra,
-                             cfg.n_extra_msa_blocks, extra_l, z_l,
-                             scan=cfg.scan_blocks,
-                             remat=False if cfg.remat == "none" else cfg.remat,
-                             block_fn=block_fn, rng=k1,
-                             deterministic=deterministic, masks=extra_masks)
-    msa_l = pre(msa, z)[0]        # z stays sharded between the two stacks
-    msa_l, z_l = evoformer_stack(params["evoformer"], cfg.evoformer,
-                                 cfg.n_evoformer, msa_l, z_l,
+    with jax.named_scope("extra_stack"):
+        _, z_l = evoformer_stack(params["extra_stack"], cfg.extra,
+                                 cfg.n_extra_msa_blocks, extra_l, z_l,
                                  scan=cfg.scan_blocks,
                                  remat=(False if cfg.remat == "none"
-                                        else cfg.remat), block_fn=block_fn,
-                                 rng=k2, deterministic=deterministic,
-                                 masks=main_masks)
+                                        else cfg.remat),
+                                 block_fn=block_fn, rng=k1,
+                                 deterministic=deterministic,
+                                 masks=extra_masks)
+    msa_l = pre(msa, z)[0]        # z stays sharded between the two stacks
+    with jax.named_scope("evoformer"):
+        msa_l, z_l = evoformer_stack(params["evoformer"], cfg.evoformer,
+                                     cfg.n_evoformer, msa_l, z_l,
+                                     scan=cfg.scan_blocks,
+                                     remat=(False if cfg.remat == "none"
+                                            else cfg.remat),
+                                     block_fn=block_fn, rng=k2,
+                                     deterministic=deterministic,
+                                     masks=main_masks)
     msa, z = post(msa_l, z_l)
     single = nn.dense(params["embedder"]["single_proj"], msa[0])
     return msa, z, single
@@ -305,8 +311,9 @@ def forward(params, cfg: AlphaFold2Config, batch, *, n_recycle=1,
         def body(i, prev):
             _, new_prev = cycle(frozen, prev, cycle_rng(rng, i), True)
             return new_prev
-        prev = jax.lax.stop_gradient(
-            jax.lax.fori_loop(0, n_recycle - 1, body, prev))
+        with jax.named_scope("recycle"):
+            prev = jax.lax.stop_gradient(
+                jax.lax.fori_loop(0, n_recycle - 1, body, prev))
     out, _ = cycle(params, prev, cycle_rng(rng, n_recycle - 1), False)
     return out
 
@@ -448,6 +455,11 @@ def loss_fn(params, cfg: AlphaFold2Config, batch, *, n_recycle=1,
             deterministic: bool = True) -> tuple:
     out = forward(params, cfg, batch, n_recycle=n_recycle, block_fn=block_fn,
                   stack_io=stack_io, rng=rng, deterministic=deterministic)
+    with jax.named_scope("loss"):
+        return _heads_and_losses(params, cfg, batch, out)
+
+
+def _heads_and_losses(params, cfg: AlphaFold2Config, batch, out) -> tuple:
     res_mask = batch["res_mask"].astype(jnp.float32)
     rots_traj, trans_traj = out["traj"]
     l_fape = heads_lib.fape_loss(rots_traj, trans_traj, batch["true_rots"],

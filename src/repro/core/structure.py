@@ -155,6 +155,11 @@ def structure_module(p: Params, cfg: StructureConfig, s_init, z,
     ``res_mask`` (r,) masks IPA keys against padded-bucket residues
     (inference); ``None`` = training fast path (loss already masks).
     """
+    with jax.named_scope("structure"):
+        return _structure_module(p, cfg, s_init, z, res_mask)
+
+
+def _structure_module(p: Params, cfg: StructureConfig, s_init, z, res_mask):
     r = s_init.shape[0]
     s = nn.dense(p["proj_s"], nn.layernorm(p["ln_s"], s_init))
     z = nn.layernorm(p["ln_z"], z)

@@ -297,6 +297,9 @@ class DataPipeline:
         self.include_row_masks = include_row_masks
         self.sharding = sharding
         self.report = StageReport()
+        # seconds the consumer waited for the batch just yielded: the
+        # ``input_wait`` span's length, 0 when the batch was already placed
+        self.wait_s = 0.0
         self._custom_make_batch = make_batch
         self.schedule = None
         if source is not None:
@@ -436,12 +439,14 @@ class DataPipeline:
         step = self.start_step
         while True:
             t0 = time.perf_counter()
+            wait_s = 0.0
             if pending is not None and pending[0] == step:
                 placed = pending[1]
                 pending = None
             else:
                 with trace_span("input_wait", tracer=self.tracer, step=step):
                     hb = host_batch(step, block=True)
+                wait_s = time.perf_counter() - t0
                 if isinstance(hb, WorkerFailure):
                     raise RuntimeError(
                         f"DataPipeline worker failed at step {step} "
@@ -462,6 +467,7 @@ class DataPipeline:
             self.report.wall_s = time.perf_counter() - t_loop
             if self.obs is not None:
                 self._mirror_report(step)
+            self.wait_s = wait_s
             yield step, placed
             step += 1
 

@@ -310,7 +310,8 @@ def run_af2(args, jax, jnp, np):
               f"{d['featurize_ms_per_step']}ms, transfer "
               f"{d['transfer_ms_per_step']}ms, fill {d['mean_fill']:.2f}")
     # end-of-run attribution: roofline-vs-measured for the full run (when
-    # --eval-every also produced windows, those rows are in the stream too)
+    # --eval-every also produced windows, those rows are in the stream too),
+    # over the mean of the steps' own host seconds
     from repro.obs import describe_attribution
     step_s = runner.history["step_s"]
     settled = step_s[1:] or step_s      # drop the compile step

@@ -15,7 +15,8 @@ repo (``TrainRunner``, ``FoldEngine``/``ContinuousScheduler``,
   ``with trace_span("featurize", step=...)`` spans across
   featurize→queue→device-put→step→eval→checkpoint (train) and
   admit→recycle-step→heads→cache (serve), exported as
-  Chrome-trace/Perfetto JSON, plus an opt-in ``jax.profiler.trace``
+  ``jax.profiler`` annotations on the device trace's clock and optionally
+  as Chrome-trace/Perfetto JSON, plus an opt-in ``jax.profiler.trace``
   capture window aligned to the same step ids.
 * :mod:`repro.obs.attribution` — the **roofline-vs-measured report**:
   measured per-step time confronted with
@@ -28,12 +29,13 @@ from repro.obs.attribution import attribution_report, describe_attribution
 from repro.obs.registry import Counter, Gauge, Histogram, MetricRegistry
 from repro.obs.sinks import ConsoleSink, JsonlSink, MemorySink
 from repro.obs.tracing import (ProfileWindow, SpanTracer, get_tracer,
-                               parse_profile_steps, set_tracer, trace_span)
+                               parse_profile_steps, set_tracer, step_span,
+                               trace_span)
 
 __all__ = [
     "MetricRegistry", "Counter", "Gauge", "Histogram",
     "MemorySink", "JsonlSink", "ConsoleSink",
-    "SpanTracer", "trace_span", "set_tracer", "get_tracer",
+    "SpanTracer", "trace_span", "step_span", "set_tracer", "get_tracer",
     "ProfileWindow", "parse_profile_steps",
     "attribution_report", "describe_attribution",
 ]

@@ -1,16 +1,21 @@
-"""Host-side span tracer with Chrome-trace/Perfetto JSON export.
+"""Host spans: on the profiler's clock, and optionally as Chrome-trace JSON.
 
-``with trace_span("step", step=7):`` records one complete event ("ph":"X")
-per exit, with per-thread nesting depth tracked so invariants (a child's
-interval lies inside its parent's) are testable.  Timestamps come from a
-single ``perf_counter`` epoch per tracer, converted to microseconds — the
-unit Chrome-trace expects.
+Every ``with trace_span("featurize", step=7):`` enters a
+``jax.profiler.TraceAnnotation`` of that name and arguments, so the span
+lands on the ``/host:CPU`` plane of any ``jax.profiler`` capture, on the
+clock of the device ops beside it.  With no capture running an annotation
+costs about a microsecond.  :func:`step_span` does the same for a training
+step with a ``jax.profiler.StepTraceAnnotation`` (``step_num``), which
+marks step boundaries for the profiler's step view.
 
-The tracer is either passed explicitly (``trace_span(name, tracer=t)``)
-or installed process-wide with :func:`set_tracer` so deep call sites
-(worker threads inside ``DataPipeline``) don't need plumbing.  When no
-tracer is active, ``trace_span`` is a no-op context manager with ~zero
-overhead.
+A :class:`SpanTracer`, when one is active, also records each span as one
+complete event ("ph":"X") per exit, with per-thread nesting depth tracked
+so invariants (a child's interval lies inside its parent's) are testable.
+Its timestamps come from a single ``perf_counter`` epoch per tracer,
+converted to microseconds — the unit Chrome-trace expects.  The tracer is
+either passed explicitly (``trace_span(name, tracer=t)``) or installed
+process-wide with :func:`set_tracer` so deep call sites (worker threads
+inside ``DataPipeline``) don't need plumbing.
 
 An optional :class:`ProfileWindow` arms ``jax.profiler.trace`` over a step
 interval ``A:B`` (``--profile-steps``) aligned to the same step ids as the
@@ -23,6 +28,8 @@ import threading
 import time
 from contextlib import contextmanager
 from typing import Optional, Tuple
+
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 
 class SpanTracer:
@@ -137,15 +144,28 @@ def get_tracer() -> Optional[SpanTracer]:
     return _GLOBAL
 
 
-@contextmanager
-def trace_span(name: str, *, tracer: Optional[SpanTracer] = None, **args):
-    """Span against ``tracer``, the global tracer, or no-op when neither."""
+def _span(annotation, name: str, tracer: Optional[SpanTracer], args: dict):
     t = tracer if tracer is not None else _GLOBAL
-    if t is None:
-        yield None
-        return
-    with t.span(name, **args):
+    return annotation if t is None else _both(annotation, t.span(name, **args))
+
+
+@contextmanager
+def _both(annotation, span):
+    with annotation, span as t:
         yield t
+
+
+def trace_span(name: str, *, tracer: Optional[SpanTracer] = None, **args):
+    """A profiler annotation ``name``; also a span of ``tracer`` or of the
+    global tracer, when either is set."""
+    return _span(TraceAnnotation(name, **args), name, tracer, args)
+
+
+def step_span(step: int, *, tracer: Optional[SpanTracer] = None, **args):
+    """The span ``step`` of training step ``step``: a profiler step
+    annotation (``step_num``), and a span like :func:`trace_span`'s."""
+    return _span(StepTraceAnnotation("step", step_num=step, **args), "step",
+                 tracer, dict(step=step, **args))
 
 
 # -- jax.profiler capture window ---------------------------------------------

@@ -43,7 +43,8 @@ def branch_parallel(branches: Sequence[Callable], *, axis: str = "branch"):
                 lambda s: jnp.zeros(s.shape, s.dtype), sh)
             outs.append(jax.lax.cond(idx == i, fn, zeros))
         # one fused exchange for all branches (paper: broadcast per tensor)
-        return jax.lax.psum(tuple(outs), axis)
+        with jax.named_scope("bp_exchange"):
+            return jax.lax.psum(tuple(outs), axis)
     return run
 
 
@@ -77,7 +78,8 @@ def bp_evoformer_block(p, cfg: EvoformerConfig, msa, z, *, rng=None,
     def branch_msa():
         msa_out = evo.msa_branch(p, cfg, msa, z, rng=rngs[0],
                                  deterministic=deterministic)
-        opm = evo.opm_apply(p["opm"], cfg, msa_out)
+        with jax.named_scope("opm"):
+            opm = evo.opm_apply(p["opm"], cfg, msa_out)
         return msa_out, opm.astype(z.dtype)
 
     def branch_pair():
@@ -86,7 +88,8 @@ def bp_evoformer_block(p, cfg: EvoformerConfig, msa, z, *, rng=None,
 
     (msa_out, opm), z_pair = branch_parallel(
         [branch_msa, branch_pair], axis=axis)()
-    return msa_out, z_pair + opm
+    with jax.named_scope("opm"):
+        return msa_out, z_pair + opm
 
 
 def bp_dap_evoformer_block(p, cfg: EvoformerConfig, msa_l, z_l, *, rng=None,

@@ -271,6 +271,7 @@ class StepWatchdog:
         self.threshold = threshold
         self.decay = decay
         self.ema: Optional[float] = None
+        self.last_s = 0.0           # the last step's own seconds
         self.flagged: list[tuple[int, float]] = []
         self.on_straggler = on_straggler
         self._t0: Optional[float] = None
@@ -279,7 +280,7 @@ class StepWatchdog:
         self._t0 = time.perf_counter()
 
     def end_step(self, step: int) -> bool:
-        dt = time.perf_counter() - self._t0
+        dt = self.last_s = time.perf_counter() - self._t0
         is_straggler = False
         if self.ema is not None and dt > self.threshold * self.ema:
             is_straggler = True

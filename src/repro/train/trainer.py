@@ -367,7 +367,7 @@ class TrainRunner:
         registry's series (DESIGN.md §14).
         """
         import jax
-        from repro.obs import get_tracer, trace_span
+        from repro.obs import get_tracer, step_span, trace_span
 
         pipeline = self.make_pipeline()
         base_rng = jax.random.PRNGKey(self.seed)
@@ -376,7 +376,6 @@ class TrainRunner:
         # cached instruments: dict lookups off the hot path (the pipeline
         # mirrors its own data/* gauges before each yield)
         h_step = obs.histogram("train/step_s")
-        c_steps = obs.counter("train/steps")
         # attribution window: reset at every report so each row attributes
         # ITS interval (not the run-so-far average)
         win_t0 = time.perf_counter()
@@ -394,8 +393,7 @@ class TrainRunner:
                 self.watchdog.start_step()
                 # fixed-recycle runs pass None: the factory's static bound
                 # keeps forward's unrolled recycling (no dead while_loop)
-                with trace_span("step", tracer=tracer, step=step,
-                                n_recycle=nr):
+                with step_span(step, tracer=tracer, n_recycle=nr):
                     self.state, metrics = self._train_step(
                         self.state, batch, jax.random.fold_in(base_rng, step),
                         nr if self.recycle_sample else None)
@@ -404,12 +402,14 @@ class TrainRunner:
                         jax.block_until_ready(metrics)
                     loss = float(metrics["loss"])  # blocks: wall-time real
                 self.watchdog.end_step(step)
-                dt = self.watchdog.ema or 0.0
+                # this step's own host seconds; the watchdog's EMA is for
+                # straggler detection only
+                dt = self.watchdog.last_s
                 obs.record("train/loss", loss, step=step)
                 obs.record("train/n_recycle", nr, step=step)
                 obs.record("train/step_s", dt, step=step)
+                obs.record("train/input_wait_s", pipeline.wait_s, step=step)
                 h_step.observe(dt)
-                c_steps.inc()
                 self.step = step + 1
                 if log_every and step % log_every == 0:
                     log(f"step {step:5d}  loss {loss:.4f}  n_recycle {nr}  "

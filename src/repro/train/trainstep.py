@@ -246,8 +246,10 @@ def make_af2_train_step(cfg, optimizer: Optimizer, plan, *,
                 acc_l, acc_g = carry
                 (l, m), g = jax.value_and_grad(
                     per_protein_loss, has_aux=True)(params, sample, r, n_rec)
-                g = complete_partial_grads(g, built.sync_axes)
-                g, _ = clip_by_global_norm(g, per_sample_clip)
+                with jax.named_scope("grad_sync"):
+                    g = complete_partial_grads(g, built.sync_axes)
+                with jax.named_scope("clip"):
+                    g, _ = clip_by_global_norm(g, per_sample_clip)
                 acc_g = jax.tree_util.tree_map(
                     lambda a, b: a + b.astype(jnp.float32), acc_g, g)
                 return (acc_l + l, acc_g), m
@@ -259,15 +261,18 @@ def make_af2_train_step(cfg, optimizer: Optimizer, plan, *,
             grads = jax.tree_util.tree_map(lambda g: g / n_local, grads)
             metrics = jax.tree_util.tree_map(jnp.mean, metrics)
 
-        grads, err = built.grad_sync(grads, err,
-                                     completed=per_sample_clip is not None)
-        if dp_axes:
-            loss = jax.lax.pmean(loss, dp_axes)
-            metrics = jax.lax.pmean(metrics, dp_axes)
-        new_params, new_opt = optimizer.update(grads, opt, params)
+        with jax.named_scope("grad_sync"):
+            grads, err = built.grad_sync(grads, err,
+                                         completed=per_sample_clip is not None)
+            if dp_axes:
+                loss = jax.lax.pmean(loss, dp_axes)
+                metrics = jax.lax.pmean(metrics, dp_axes)
+        with jax.named_scope("optimizer"):
+            new_params, new_opt = optimizer.update(grads, opt, params)
         out = {"params": new_params, "opt": new_opt}
         if ema is not None:
-            out["ema"] = ema.update(state["ema"], new_params)
+            with jax.named_scope("ema"):
+                out["ema"] = ema.update(state["ema"], new_params)
         if err is not None:
             out["err"] = err
         metrics = dict(metrics)

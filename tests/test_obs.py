@@ -310,8 +310,81 @@ def test_trainrunner_history_is_registry_view_and_spans_cover_stages(
     # step spans carry their step ids
     steps = sorted(e["args"]["step"] for e in tr.spans("step"))
     assert steps == [0, 1, 2, 3]
+    # per-step events, one per step; no step counter (the step ids on every
+    # event carry the count)
+    assert len(reg.series("train/step_s")) == 4
+    assert len(reg.series("train/input_wait_s")) == 4
+    assert "train/steps" not in reg.snapshot()
     # checkpoint timings flowed through the registry
     assert len(reg.series("ckpt/save_s")) >= 1
+
+
+@pytest.fixture(scope="module")
+def runner():
+    """One compiled TrainRunner (no SpanTracer) for the loop tests below;
+    each continues from the step the last one left it at."""
+    from repro.train.trainer import TrainRunner
+    r = TrainRunner(_cfg(), batch_size=1, seed=0, n_recycle=1,
+                    recycle_sample=False)
+    r.run(1)                            # compiles the step
+    return r
+
+
+def test_trainrunner_step_s_is_each_steps_own_time(runner):
+    """``train/step_s`` is the step's own host seconds, not the watchdog's
+    EMA (which skips a straggler and so never sees a slow step)."""
+    import time
+    step, slow = runner._train_step, runner.step + 1
+
+    def slowed(state, batch, rng, nr):
+        out = step(state, batch, rng, nr)
+        if runner.step == slow:
+            time.sleep(0.5)
+        return out
+    runner._train_step = slowed
+    try:
+        hist = runner.run(slow + 1)
+    finally:
+        runner._train_step = step
+    # the slowed step reads its sleep; the step before it carries nothing
+    # of step 0, which compiled (an EMA would carry most of it)
+    assert hist["step_s"][slow] >= 0.5
+    assert hist["step_s"][slow - 1] < 0.5 * hist["step_s"][0]
+    waits = runner.obs.series("train/input_wait_s")
+    assert len(waits) == len(hist["step_s"]) == slow + 1
+    assert all(w >= 0.0 for w in waits)
+
+
+def _host_events(logdir, names):
+    import glob
+    from jax.profiler import ProfileData
+    path, = glob.glob(f"{logdir}/**/*.xplane.pb", recursive=True)
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name == "/host:CPU":
+            for line in plane.lines:
+                out += [(ev.name, dict(ev.stats)) for ev in line.events
+                        if ev.name in names]
+    return out
+
+
+def test_trainrunner_spans_reach_the_profiler(runner, tmp_path):
+    """With no SpanTracer installed, the loop's spans still land on the
+    profiler's host plane: ``step`` as a step annotation with its
+    ``step_num``, and the loop's ``input_wait`` (the pipeline may already
+    wait for the batch after the last step)."""
+    assert get_tracer() is None and runner.tracer is None
+    first = runner.step
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        runner.run(first + 2)
+    finally:
+        jax.profiler.stop_trace()
+    events = _host_events(tmp_path, {"step", "input_wait"})
+    steps = sorted(st["step_num"] for name, st in events if name == "step")
+    assert steps == [first, first + 1]
+    waits = sorted(st["step"] for name, st in events if name == "input_wait")
+    assert waits and set(waits) <= {first, first + 1, first + 2}
 
 
 # ---------------------------------------------------------------------------
