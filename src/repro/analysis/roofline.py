@@ -151,8 +151,9 @@ def tri_mult_hbm_bytes(cfg, impl: str = None, *, dap: int = 1,
     * ``reference``: the LN'd input round-trips for 5 projections, the
       (r, r, 2c) gated pair + the pre-gate output + epilogue tensors all
       write+read HBM — ~(8·c_z + 6·c_mul) elements per position;
-    * ``chunked``: the gated pair never materializes, but the fp32 slab
-      accumulator re-round-trips once per k-chunk;
+    * ``chunked``: the gated pair never materializes; the fp32 product
+      round-trips once when c_mul <= c_z (the one-pass path), else once
+      per k-chunk of the slab path;
     * ``pallas``: only the LN'd input (the xb operand streamed once per
       i-block row), the gate source and the output touch HBM — the kernel's
       arithmetic intensity is what ``auto_plan`` sees.
@@ -164,7 +165,7 @@ def tri_mult_hbm_bytes(cfg, impl: str = None, *, dap: int = 1,
     if impl == "reference":
         per_op = elt * area * (8 * z + 6 * c_mul)
     elif impl == "chunked":
-        n_k = -(-r // max(1, e.tri_mult_chunk))
+        n_k = 1 if c_mul <= z else -(-r // max(1, e.tri_mult_chunk))
         per_op = elt * area * 6 * z + 4 * area * c_mul * 2 * n_k
     elif impl == "pallas":
         n_i = -(-r // min(r, 128))        # xb streamed once per i-block

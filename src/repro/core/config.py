@@ -29,12 +29,13 @@ class EvoformerConfig:
     opm_impl: str = "fused"
     opm_chunk: int = 32               # residue rows per fused-OPM chunk
     # triangle multiplicative update (Algorithms 11/12):
-    # 'reference' (naive XLA, fp32-accumulating oracle) | 'chunked' (i/k-
-    # chunked online accumulation + per-slab epilogue: no (r, r, 2·c_mul)
-    # gated-projection pair, any backend) | 'pallas' (fully fused kernel,
-    # interpret on CPU / Mosaic on TPU)
+    # 'reference' (naive XLA, fp32-accumulating oracle) | 'chunked' (XLA,
+    # any backend, no (r, r, 2·c_mul) gated-projection pair: one pass when
+    # c_mul <= c_z, else i/k-chunked online accumulation + per-slab
+    # epilogue) | 'pallas' (fully fused kernel, interpret on CPU / Mosaic
+    # on TPU)
     tri_mult_impl: str = "chunked"
-    tri_mult_chunk: int = 64          # i/k slab extent of the chunked impl
+    tri_mult_chunk: int = 64          # i/k slab extent of the slab path
 
 
 @dataclasses.dataclass(frozen=True)

@@ -371,30 +371,63 @@ def _tri_mult_packed_weights(p: Params):
     return w_a, b_a, w_b, b_b
 
 
+def _tri_mult_onepass(p: Params, xa: jnp.ndarray, xb: jnp.ndarray,
+                      xg: jnp.ndarray, *, outgoing: bool, out_dtype,
+                      k_mask: Optional[jnp.ndarray]) -> jnp.ndarray:
+    """The chunked impl when c_hidden <= c_z: each gated operand projected
+    once, one fp32 k-contraction, then the out-LN/out-proj/gate epilogue.
+    Its largest tensors are the fp32 contraction (r_i, r_j, c_hidden) and
+    the reps themselves.  Incoming edges contract over axis 0 as given:
+    swapping the rep first costs a transpose copy of it on a TPU."""
+    a = jax.nn.sigmoid(nn.dense(p["a_gate"], xa)) * nn.dense(p["a"], xa)
+    if k_mask is not None:
+        km = k_mask.astype(a.dtype)
+        a = a * (km[None, :, None] if outgoing else km[:, None, None])
+    b = jax.nn.sigmoid(nn.dense(p["b_gate"], xb)) * nn.dense(p["b"], xb)
+    o = jnp.einsum("ikc,jkc->ijc" if outgoing else "kic,kjc->ijc", a, b,
+                   preferred_element_type=jnp.float32)
+    o = nn.dense(p["out"], nn.layernorm(p["ln_out"], o.astype(out_dtype)))
+    g = jax.nn.sigmoid(nn.dense(p["gate"], xg))
+    return (g * o).astype(out_dtype)
+
+
 def triangle_mult_fused(p: Params, xa: jnp.ndarray, xb: jnp.ndarray,
                         xg: jnp.ndarray, *, impl: str, chunk: int = 64,
-                        out_dtype=None,
+                        out_dtype=None, outgoing: bool = True,
                         k_mask: Optional[jnp.ndarray] = None) -> jnp.ndarray:
     """Fused triangle-mult core shared by the serial and DAP paths.
 
-    Operands are already LN'd and oriented so that
-    ``o[i,j,c] = sum_k a(xa[i,k])·b(xb[j,k])`` covers both edge directions
-    (incoming = outgoing on the transposed rep) and DAP row-sharding
-    (xa/xg row-sharded, xb gathered — see ``parallel.dap.dap_triangle_mult``).
+    Operands are already LN'd so that ``o[i,j,c] = sum_k a(xa[i,k])·
+    b(xb[j,k])`` (outgoing) or ``sum_k a(xa[k,i])·b(xb[k,j])`` (incoming,
+    ``outgoing=False``), which also covers DAP row-sharding (i is a shard
+    of rows, xb gathered — see ``parallel.dap.dap_triangle_mult``).  The
+    Pallas kernel and the slab path take incoming edges as outgoing ones on
+    the transposed operands.
 
     impl='pallas': the Pallas kernel (``kernels.triangle``) — nothing
-    between xa/xb and the gated output touches HBM.  impl='chunked': XLA
-    fallback for the CPU dry-run backend; i-rows are processed in ``chunk``
-    slabs, each running a k-chunked fp32 online accumulation followed
-    immediately by its out-LN/out-proj/gate epilogue — neither the
-    (r, r, 2·c_hidden) gated-projection pair nor any full-size pre-gate
-    tensor is ever materialized (jaxpr-pinned by tests/test_triangle.py).
+    between xa/xb and the gated output touches HBM.  impl='chunked': XLA.
+    When c_hidden <= c_z each gated projection holds no more elements than
+    the rep passed in, so it runs in one pass (``_tri_mult_onepass``): a and
+    b projected once over the whole operand, one fp32 k-contraction, one
+    epilogue.  When c_hidden > c_z, i-rows are processed in ``chunk`` slabs,
+    each running a k-chunked fp32 online accumulation followed immediately
+    by its out-LN/out-proj/gate epilogue, so no full (r, r, c_hidden)
+    projection is ever materialized; ``chunk`` is that slab path's row and
+    k tile and means nothing to the one-pass path.  Neither path builds the
+    (r, r, 2·c_hidden) gated-projection pair (jaxpr-pinned by
+    tests/test_triangle.py).
 
     ``k_mask`` (r_k,) additionally drops padded-bucket residues from the
     k-contraction (inference; both impls honor it — the Pallas kernel takes
     it as a streamed operand via the forward-only masked entry point).
     """
     out_dtype = out_dtype or xg.dtype
+    if impl == "chunked" and p["a"]["w"].shape[1] <= xa.shape[-1]:
+        with jax.named_scope("tri_mult_onepass"):
+            return _tri_mult_onepass(p, xa, xb, xg, outgoing=outgoing,
+                                     out_dtype=out_dtype, k_mask=k_mask)
+    if not outgoing:
+        xa, xb = xa.swapaxes(0, 1), xb.swapaxes(0, 1)
     if impl == "pallas":
         from repro.kernels import ops as kops
         w_a, b_a, w_b, b_b = _tri_mult_packed_weights(p)
@@ -485,11 +518,9 @@ def tri_mult_apply(p: Params, cfg: EvoformerConfig, z: jnp.ndarray, *,
     if impl not in ("chunked", "pallas"):
         raise ValueError(f"unknown tri_mult impl {impl!r}")
     x = nn.layernorm(p["ln_in"], z)
-    xab = x if outgoing else x.swapaxes(0, 1)
-    # both orientations keep k on axis 1 of xa/xb, so the same (r,) mask works
-    return triangle_mult_fused(p, xab, xab, x, impl=impl,
+    return triangle_mult_fused(p, x, x, x, impl=impl,
                                chunk=cfg.tri_mult_chunk, out_dtype=z.dtype,
-                               k_mask=k_mask)
+                               outgoing=outgoing, k_mask=k_mask)
 
 
 # ---------------------------------------------------------------------------

@@ -197,17 +197,17 @@ def dap_triangle_mult(p, z_l, *, outgoing: bool, axis_name: str = AXIS,
         else:
             # out[i_l, j] = sum_k a(x[k, i_l]) b(x[k, j]): the gathered rep
             # already holds every element — slice this device's i-columns
-            # out of it locally (no extra all_to_all) and transpose both
+            # out of it locally (no extra all_to_all)
             lo = jax.lax.axis_index(axis_name) * z_l.shape[0]
             xa = jax.lax.dynamic_slice_in_dim(
-                x_full, lo, z_l.shape[0], axis=1).swapaxes(0, 1)
-            xb = x_full.swapaxes(0, 1)
+                x_full, lo, z_l.shape[0], axis=1)
+            xb = x_full
         if impl == "pallas" and not evo.tri_mult_supported(
-                xa.shape[0], xb.shape[0], xa.shape[1]):
+                z_l.shape[0], *x_full.shape[:2]):
             impl = "chunked"
         return evo.triangle_mult_fused(p, xa, xb, x_l, impl=impl,
                                        chunk=chunk, out_dtype=z_l.dtype,
-                                       k_mask=k_mask)
+                                       outgoing=outgoing, k_mask=k_mask)
     x = nn.layernorm(p["ln_in"], z_l)
     a = jax.nn.sigmoid(nn.dense(p["a_gate"], x)) * nn.dense(p["a"], x)
     b = jax.nn.sigmoid(nn.dense(p["b_gate"], x)) * nn.dense(p["b"], x)

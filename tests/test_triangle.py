@@ -9,11 +9,17 @@ import numpy as np
 import pytest
 
 from repro.core import evoformer as evo
-from repro.core.config import af2_tiny
+from repro.core.config import af2_initial, af2_tiny
 from repro.nn import layers as nn
-from tests.util import max_eqn_elems, randomize
+from tests.util import max_eqn_elems, randomize, run_subprocess
 
 pallas_interpret = pytest.mark.pallas_interpret
+
+# (c_z, c_hidden) per path of the chunked impl: c_hidden <= c_z runs in one
+# pass, c_hidden > c_z in row slabs with a k-scan
+PATHS = {"onepass": (16, 16), "slab": (8, 32)}
+over_paths = pytest.mark.parametrize("c_z,c", list(PATHS.values()),
+                                     ids=list(PATHS))
 
 
 def _cfg(impl, chunk=64):
@@ -28,26 +34,29 @@ def _setup(r, c_z=16, c=16, seed=0):
     return p, z
 
 
-def _grads(p, cfg, z, outgoing):
+def _grads(p, cfg, z, outgoing, k_mask=None):
     w = jnp.cos(jnp.arange(z.shape[-1]))  # non-uniform cotangent
 
     def loss(p, z):
-        return (evo.tri_mult_apply(p, cfg, z, outgoing=outgoing) * w).sum()
+        return (evo.tri_mult_apply(p, cfg, z, outgoing=outgoing,
+                                   k_mask=k_mask) * w).sum()
 
     return jax.jit(jax.grad(loss, argnums=(0, 1)))(p, z)
 
 
-def _assert_impl_matches(impl, r, chunk=64, fwd_tol=1e-5, grad_tol=1e-4):
-    p, z = _setup(r)
+def _assert_impl_matches(impl, r, chunk=64, fwd_tol=1e-5, grad_tol=1e-4,
+                         c_z=16, c=16, k_mask=None):
+    p, z = _setup(r, c_z, c)
     for outgoing in (True, False):
-        ref = evo.tri_mult_apply(p, _cfg("reference"), z, outgoing=outgoing)
+        ref = evo.tri_mult_apply(p, _cfg("reference"), z, outgoing=outgoing,
+                                 k_mask=k_mask)
         out = jax.jit(lambda p, z: evo.tri_mult_apply(
-            p, _cfg(impl, chunk), z, outgoing=outgoing))(p, z)
+            p, _cfg(impl, chunk), z, outgoing=outgoing, k_mask=k_mask))(p, z)
         np.testing.assert_allclose(np.asarray(ref), np.asarray(out),
                                    rtol=fwd_tol, atol=fwd_tol,
                                    err_msg=f"{impl} fwd outgoing={outgoing}")
-        gp_r, gz_r = _grads(p, _cfg("reference"), z, outgoing)
-        gp, gz = _grads(p, _cfg(impl, chunk), z, outgoing)
+        gp_r, gz_r = _grads(p, _cfg("reference"), z, outgoing, k_mask)
+        gp, gz = _grads(p, _cfg(impl, chunk), z, outgoing, k_mask)
         np.testing.assert_allclose(np.asarray(gz_r), np.asarray(gz),
                                    rtol=grad_tol, atol=grad_tol,
                                    err_msg=f"{impl} dz outgoing={outgoing}")
@@ -60,15 +69,28 @@ def _assert_impl_matches(impl, r, chunk=64, fwd_tol=1e-5, grad_tol=1e-4):
                         f"outgoing={outgoing}")
 
 
+@over_paths
 @pytest.mark.parametrize("r", [64, 128])
-def test_chunked_matches_reference(r):
-    _assert_impl_matches("chunked", r)
+def test_chunked_matches_reference(r, c_z, c):
+    _assert_impl_matches("chunked", r, c_z=c_z, c=c)
 
 
-def test_chunked_non_dividing_chunk():
+@over_paths
+def test_chunked_non_dividing_chunk(c_z, c):
     """Padded k columns project through non-zero biases — they must be
-    masked out, not silently summed (48 % 20 != 0 exercises both pads)."""
-    _assert_impl_matches("chunked", 48, chunk=20)
+    masked out, not silently summed (48 % 20 != 0 exercises both pads of
+    the slab path; the one-pass path ignores the chunk)."""
+    _assert_impl_matches("chunked", 48, chunk=20, c_z=c_z, c=c)
+
+
+@pytest.mark.parametrize("c_z,c", [*PATHS.values(), (16, 8)],
+                         ids=[*PATHS, "onepass_narrow"])
+def test_chunked_k_mask_matches_reference(c_z, c):
+    """A padded bucket's residues leave the k-contraction on both paths,
+    forward and gradients (a padded entry's gated projection is not 0)."""
+    k_mask = jnp.arange(40) < 33
+    _assert_impl_matches("chunked", 40, chunk=16, c_z=c_z, c=c,
+                         k_mask=k_mask)
 
 
 @pallas_interpret
@@ -233,6 +255,120 @@ def test_chunked_backward_also_bounded():
         lambda z: evo.tri_mult_apply(p, cfg, z, outgoing=True).sum()))(z))
     assert peak <= r * r * c, peak
     assert peak < r * r * 2 * c, peak
+
+
+def test_onepass_bounded_by_the_rep():
+    """The one-pass path builds each gated projection whole, which is only
+    allowed because c_hidden <= c_z keeps it no larger than the rep: forward
+    and backward, nothing reaches the (r, r, 2c) pair, and nothing exceeds
+    the larger of the rep and the fp32 contraction (r, r, c)."""
+    r = 32
+    for c_z, c in ((16, 16), (16, 12)):
+        p, _ = _setup(r, c_z, c)
+        z = jax.random.normal(jax.random.PRNGKey(2), (r, r, c_z))
+        cfg = _cfg("chunked")
+        for outgoing in (True, False):
+            f = lambda z: evo.tri_mult_apply(p, cfg, z, outgoing=outgoing)
+            for name, jx in (
+                    ("fwd", jax.make_jaxpr(f)(z)),
+                    ("grad", jax.make_jaxpr(jax.grad(
+                        lambda z: f(z).sum()))(z))):
+                peak = max_eqn_elems(jx)
+                assert peak < r * r * 2 * c, (c_z, c, outgoing, name, peak)
+                assert peak <= max(r * r * c_z, r * r * c), (
+                    c_z, c, outgoing, name, peak)
+
+
+def test_onepass_clean_for_materialization_pass_at_model1_widths():
+    """The lint's TRIMULT_PAIR_MATERIALIZED bound holds for the one-pass
+    path at the benchmark cells' widths (r 256, c_z = c_hidden_mul 128)."""
+    from repro.analysis.static.core import Program
+    from repro.analysis.static.passes import MaterializationPass
+    from repro.analysis.static.passes.materialization import size_thresholds
+    cfg = af2_initial()
+    ev = cfg.evoformer
+    assert ev.tri_mult_impl == "chunked" and ev.c_hidden_mul <= ev.c_z
+    assert any(code == "TRIMULT_PAIR_MATERIALIZED"
+               for *_, code in size_thresholds(cfg)), "bound not armed"
+    p = jax.eval_shape(lambda: evo.triangle_mult_init(
+        jax.random.PRNGKey(0), ev.c_z, ev.c_hidden_mul))
+    z = jax.ShapeDtypeStruct((cfg.n_res, cfg.n_res, ev.c_z), jnp.bfloat16)
+
+    def both(p, z):
+        return sum(evo.tri_mult_apply(p, ev, z, outgoing=o).astype(
+            jnp.float32).sum() for o in (True, False))
+
+    jaxprs = {"fwd": jax.make_jaxpr(both)(p, z),
+              "step": jax.make_jaxpr(jax.grad(both, argnums=(0, 1)))(p, z)}
+    res = MaterializationPass().run(Program(
+        name="tri_mult:af2_initial", kind="fixture", jaxprs=jaxprs,
+        meta={"cfg": cfg}))
+    assert not res.skipped
+    hits = [f for f in res.findings if f.code == "TRIMULT_PAIR_MATERIALIZED"]
+    assert not hits, [f.message for f in hits]
+
+
+@pytest.mark.parametrize("c_z,c,engaged", [(16, 16, True), (16, 8, True),
+                                           (8, 32, False)])
+def test_onepass_scope_marks_engagement(c_z, c, engaged):
+    """``tri_mult_onepass`` names the path's ops in the compiled program's
+    op metadata (what the device trace reads) exactly when c_hidden <= c_z."""
+    p, z = _setup(16, c_z, c)
+    cfg = _cfg("chunked", chunk=8)
+    for outgoing in (True, False):
+        hlo = jax.jit(lambda p, z: evo.tri_mult_apply(
+            p, cfg, z, outgoing=outgoing)).lower(p, z).compile().as_text()
+        assert ("tri_mult_onepass" in hlo) == engaged, (outgoing, engaged)
+
+
+def test_dap_onepass_matches_unsharded():
+    """DAP's tri-mult (row-sharded a, gathered b) takes the one-pass path
+    under the same rule and matches the unsharded one, in both edge
+    directions, with and without a k_mask, forward and gradients."""
+    run_subprocess("""
+import jax, jax.numpy as jnp, numpy as np
+from jax.sharding import PartitionSpec as P
+from repro.core import evoformer as evo
+from repro.core.config import af2_tiny
+from repro.parallel import dap as dap_lib
+from repro.parallel.mesh_utils import local_slice, make_mesh, smap
+from tests.util import randomize
+
+ev = af2_tiny().evoformer
+assert ev.tri_mult_impl == "chunked" and ev.c_hidden_mul <= ev.c_z
+r = 16
+p = randomize(evo.triangle_mult_init(jax.random.PRNGKey(0), ev.c_z,
+                                     ev.c_hidden_mul), jax.random.PRNGKey(7))
+z = jax.random.normal(jax.random.PRNGKey(1), (r, r, ev.c_z))
+w = jnp.cos(jnp.arange(ev.c_z))
+mesh = make_mesh((2,), ("dap",))
+for outgoing in (True, False):
+    for k_mask in (None, jnp.arange(r) < 13):
+        def dap(p, z):
+            def body(p, z):
+                z_l = local_slice(z, "dap", 0)
+                y = dap_lib.dap_triangle_mult(
+                    p, z_l, outgoing=outgoing, axis_name="dap",
+                    impl="chunked", chunk=ev.tri_mult_chunk, k_mask=k_mask)
+                return dap_lib._all_gather(y, "dap", 0)
+            return smap(body, mesh, (P(), P()), P())(p, z)
+        one = lambda p, z: evo.tri_mult_apply(p, ev, z, outgoing=outgoing,
+                                              k_mask=k_mask)
+        hlo = jax.jit(dap).lower(p, z).compile().as_text()
+        assert "tri_mult_onepass" in hlo, "DAP did not take the one-pass path"
+        np.testing.assert_allclose(np.asarray(jax.jit(one)(p, z)),
+                                   np.asarray(jax.jit(dap)(p, z)),
+                                   rtol=1e-5, atol=1e-5)
+        g1 = jax.jit(jax.grad(lambda p, z: (one(p, z) * w).sum(),
+                              argnums=(0, 1)))(p, z)
+        g2 = jax.jit(jax.grad(lambda p, z: (dap(p, z) * w).sum(),
+                              argnums=(0, 1)))(p, z)
+        for a, b in zip(jax.tree_util.tree_leaves(g1),
+                        jax.tree_util.tree_leaves(g2)):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=1e-4, atol=1e-4)
+        print("dap one-pass ok", outgoing, k_mask is not None)
+""", devices=2, timeout=300)
 
 
 # ---------------------------------------------------------------------------
